@@ -31,6 +31,9 @@ def main(argv=None) -> int:
     p.add_argument("--only", default=None)
     args = p.parse_args(argv)
     import importlib
+
+    from repro import compile_cache
+    compile_cache.enable()
     failures = 0
     for name, mod in MODULES:
         if args.only and args.only not in name:

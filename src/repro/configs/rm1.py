@@ -5,6 +5,8 @@ over six generations / three years. Dense compute grows mildly.
 Sizes are synthetic-projection endpoints from the paper; intermediate
 generations interpolate geometrically (x~1.41/gen).
 """
+import dataclasses
+
 from repro.configs.base import DLRMConfig, ModelConfig
 
 _EMBED_DIM = 128
@@ -47,6 +49,23 @@ def size_bytes(v: int) -> int:
 
 CONFIG = generation(0)
 GENERATIONS = [generation(v) for v in range(6)]
+
+# Chip-share RM1: RM1 V0 (source: DisaggRec Fig. 1b, V0 = 1.4 TB of fp32
+# tables) at every published width — 800 tables, embed_dim 128,
+# avg_pooling 80, 256 dense features, bottom MLP (512, 256, 128), top MLP
+# (1024, 1024, 512, 256, 1), interaction_proj 64, fp32.
+# reduced: rows_per_table 3,417,969 -> 4,096.
+# Deployment: one TPU v5e (16 GB HBM) holding one chip's share of a 4-MN
+# pool — the whole {CN, MN} cluster of ClusterEngine runs on that chip.
+# Tables are 800 x 4,096 x 128 x 4 B = 1.68 GB; the engine keeps the
+# (T, R, D) stack plus n_replicas=2 shard copies, about 5 GB in all.
+CHIP_SHARE = CONFIG.replace(
+    name="rm1-chip",
+    dlrm=dataclasses.replace(CONFIG.dlrm, rows_per_table=4_096),
+    notes="RM1 V0 (DisaggRec Fig. 1b) at published widths; reduced: "
+          "rows_per_table 3,417,969 -> 4,096 (one chip's share of a "
+          "4-MN pool)",
+)
 
 REDUCED = CONFIG.replace(
     name="rm1-reduced",

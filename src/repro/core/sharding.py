@@ -16,7 +16,6 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import embedding_manager as em
@@ -66,11 +65,11 @@ def disagg_embedding_lookup(tables, idx, mesh=None, axis: str = "model",
         # Fsum all-gather: only pooled vectors cross the network
         return jax.lax.all_gather(pooled, axis, axis=1, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(axis, None, None), P(bspec, None, None)),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(tables, idx)
 
 

@@ -1,15 +1,22 @@
-"""Fused embedding-bag (gather + pooling) Pallas kernel.
+"""Fused embedding-bag (gather + pooling) Pallas kernels.
 
-Near-memory reduction on TPU: the table lives in HBM; the grid walks
-(bag, pooling-slot) and the BlockSpec index_map — driven by the
-scalar-prefetched index array — streams exactly the needed (1, D) rows
-into VMEM, double-buffered by the Pallas pipeline. Accumulation happens
-in the revisited VMEM output block, so raw rows never cross back to HBM:
-only the pooled Fsum is written out — the paper's NMP-DIMM insight,
-VMEM-local.
+Near-memory reduction on TPU: the table lives in HBM and only the pooled
+Fsum is ever written out — the paper's NMP-DIMM insight, VMEM-local.
 
-Padding indices are negative: their loads are clamped to row 0 and the
-accumulate is predicated off.
+``embedding_bag_1table`` / ``embedding_bag`` stream one (1, D) row per
+(bag, slot) grid step through a scalar-prefetch-driven BlockSpec; that
+block shape is below the TPU tile, so they serve interpret mode only.
+
+The shard kernels on the serving path (``embedding_bag_fused_flat``,
+``embedding_bag_nmp_flat``) keep the flat shard in HBM
+(``memory_space=pl.ANY``) and gather each bag's rows with one DMA per
+valid slot into a (P, D) VMEM scratch, then sum them in ascending slot
+order.  Their index block is a per-grid-step SMEM tile, and their output
+block spans the full last two dimensions, so the v5e compiler accepts
+them at published widths.
+
+Padding indices are negative: their DMA is skipped and the accumulate is
+predicated off (the old accumulator is selected, never ``acc + 0.0``).
 """
 from __future__ import annotations
 
@@ -70,19 +77,69 @@ def embedding_bag(tables: jax.Array, idx: jax.Array,
     return out.astype(tables.dtype)
 
 
-# --------------------------------------------------------- fused multi-table
-def _fused_kernel(idx_ref, off_ref, table_blk, out_blk):
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    p = pl.program_id(2)
+# ------------------------------------------------------- shard bag kernels
+def _gather_pool(slot_row, base, table_hbm, rows, sems):
+    """Pool one bag: DMA its valid rows ``table_hbm[base + slot_row(p)]``
+    into ``rows[p]`` (one semaphore per slot), then add them to a
+    (1, D) fp32 accumulator in ascending slot order.  Padding slots
+    (``slot_row(p) < 0``) issue no DMA and keep the old accumulator."""
+    P, D = rows.shape
 
-    @pl.when(p == 0)
-    def _init():
-        out_blk[...] = jnp.zeros_like(out_blk)
+    def copy(p):
+        src = table_hbm.at[pl.ds(base + slot_row(p), 1)]
+        return pltpu.make_async_copy(src, rows.at[pl.ds(p, 1)], sems.at[p])
 
-    @pl.when(idx_ref[b, t, p] >= 0)
-    def _acc():
-        out_blk[...] += table_blk[...].astype(out_blk.dtype)
+    def start(p, carry):
+        @pl.when(slot_row(p) >= 0)
+        def _():
+            copy(p).start()
+        return carry
+
+    def wait_add(p, acc):
+        valid = slot_row(p) >= 0
+
+        @pl.when(valid)
+        def _():
+            copy(p).wait()
+        row = rows[pl.ds(p, 1), :].astype(jnp.float32)
+        return jnp.where(valid, acc + row, acc)
+
+    jax.lax.fori_loop(0, P, start, 0)
+    return jax.lax.fori_loop(0, P, wait_add, jnp.zeros((1, D), jnp.float32))
+
+
+def _shard_call(kernel, flat_table, offsets, idx_blocked, interpret):
+    """pallas_call shared by both shard kernels: grid over the leading
+    axis of ``idx_blocked`` (G, N, P), its (1, N, P) tile in SMEM per
+    step, the shard in HBM, a (1, N, D) fp32 output block."""
+    _, D = flat_table.shape
+    G, N, P = idx_blocked.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(G,),
+        in_specs=[pl.BlockSpec((1, N, P), lambda g, off: (g, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, N, D), lambda g, off: (g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((P, D), flat_table.dtype),
+                        pltpu.SemaphoreType.DMA((P,))],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((G, N, D), jnp.float32),
+        interpret=interpret,
+    )(offsets, idx_blocked, flat_table)
+
+
+def _fused_kernel(off_ref, idx_ref, table_hbm, out_ref, rows, sems):
+    # one grid step per bag b; its tables pool in turn
+    def per_table(t, carry):
+        out_ref[0, pl.ds(t, 1), :] = _gather_pool(
+            lambda p: idx_ref[0, t, p], off_ref[t], table_hbm, rows, sems)
+        return carry
+
+    jax.lax.fori_loop(0, idx_ref.shape[1], per_table, 0)
 
 
 def embedding_bag_fused_flat(flat_table: jax.Array, offsets: jax.Array,
@@ -91,40 +148,18 @@ def embedding_bag_fused_flat(flat_table: jax.Array, offsets: jax.Array,
     """One Pallas call pooling every table of a (flattened) shard.
 
     flat_table: (sum_t R_t, D) — all tables stacked row-wise, so tables of
-    different row counts coexist in one shard buffer.
+    different row counts coexist in one shard buffer; it stays in HBM.
     offsets:    (T,) int32 — scalar-prefetched row offset of each table in
-    flat_table; with idx, it drives the BlockSpec index_map so the pipeline
-    streams exactly one (1, D) row per (bag, table, slot) grid step.
+    flat_table.
     idx:        (B, T, P) int32, table-local rows, -1 padded.
 
-    Returns pooled (B, T, D) fp32. Grid order (B, T, P) makes P innermost:
-    each (b, t) output block is revisited P times and accumulated in VMEM —
-    raw rows never return to HBM, only the pooled Fsum (the NMP insight,
-    now amortizing ONE kernel launch across the whole shard instead of one
-    vmapped call per table).
+    Returns pooled (B, T, D) fp32.  The grid walks bags: step b holds
+    bag b's (T, P) indices in SMEM and its (T, D) output block in VMEM,
+    and for each table DMAs the valid rows and sums them in ascending
+    slot order — raw rows never return to HBM, only the pooled Fsum (the
+    NMP insight, amortizing ONE kernel launch across the whole shard).
     """
-    _, D = flat_table.shape
-    B, T, P = idx.shape
-
-    def table_map(b, t, p, idx_ref, off_ref):
-        # clamp padding to the table's row 0; accumulate is masked off
-        return off_ref[t] + jnp.maximum(idx_ref[b, t, p], 0), 0
-
-    def out_map(b, t, p, idx_ref, off_ref):
-        return b, t, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, T, P),
-        in_specs=[pl.BlockSpec((1, D), table_map)],
-        out_specs=pl.BlockSpec((1, 1, D), out_map),
-    )
-    return pl.pallas_call(
-        _fused_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, D), jnp.float32),
-        interpret=interpret,
-    )(idx, offsets, flat_table)
+    return _shard_call(_fused_kernel, flat_table, offsets, idx, interpret)
 
 
 def embedding_bag_fused(tables: jax.Array, idx: jax.Array,
@@ -138,20 +173,17 @@ def embedding_bag_fused(tables: jax.Array, idx: jax.Array,
 
 
 # ------------------------------------------------------ near-memory pooling
-def _nmp_kernel(idx_ref, off_ref, table_ref, out_blk, *, pool: int):
+def _nmp_kernel(off_ref, idx_ref, table_hbm, out_ref, rows, sems):
+    # one grid step per table t (its (B, P) indices in SMEM); its bags
+    # reduce in turn against the table's offset
     t = pl.program_id(0)
-    b = pl.program_id(1)
 
-    def body(p, acc):
-        r = off_ref[t] + jnp.maximum(idx_ref[b, t, p], 0)
-        row = table_ref[pl.ds(r, 1), :].astype(jnp.float32)
-        # exact skip for padding: select the OLD accumulator, never add 0.0
-        # (keeps -0.0 rows bitwise and matches the fused kernel's predicate)
-        return jnp.where(idx_ref[b, t, p] >= 0, acc + row[None], acc)
+    def per_bag(b, carry):
+        out_ref[0, pl.ds(b, 1), :] = _gather_pool(
+            lambda p: idx_ref[0, b, p], off_ref[t], table_hbm, rows, sems)
+        return carry
 
-    acc = jax.lax.fori_loop(0, pool, body,
-                            jnp.zeros(out_blk.shape, jnp.float32))
-    out_blk[...] = acc
+    jax.lax.fori_loop(0, idx_ref.shape[1], per_bag, 0)
 
 
 def embedding_bag_nmp_flat(flat_table: jax.Array, offsets: jax.Array,
@@ -160,43 +192,25 @@ def embedding_bag_nmp_flat(flat_table: jax.Array, offsets: jax.Array,
     """On-MN pooling kernel for an NMP memory node (paper §NMP, Fig. 14).
 
     Same contract as ``embedding_bag_fused_flat`` — flat_table
-    (sum_t R_t, D) with scalar-prefetched per-table ``offsets`` and
-    table-local ``idx`` (B, T, P), -1 padded — but a different execution
-    shape that mirrors the NMP-DIMM: the grid walks (table, bag) — one
-    step per *pooled output* — and the whole bag reduces inside the
-    kernel body with a sequential ``fori_loop`` over pooling slots,
-    accumulating in a local register/VMEM accumulator.  Rows are fetched
-    with dynamic slices from the resident shard buffer (the DIMM-rank
-    fetch; on real NMP hardware each fetch stays inside the rank), and
-    only the D-dim pooled Fsum is ever written out — the memory node
-    ships ``tables x D`` bytes to the CN instead of ``rows x D``.
+    (sum_t R_t, D) in HBM with scalar-prefetched per-table ``offsets``
+    and table-local ``idx`` (B, T, P), -1 padded — but a different
+    execution shape that mirrors the NMP-DIMM: the grid walks tables —
+    the node scans its shard table by table — and every bag of the
+    table reduces inside the kernel body, rows fetched by DMA from the
+    shard (the DIMM-rank fetch; on real NMP hardware each fetch stays
+    inside the rank).  Only the D-dim pooled Fsum is ever written out —
+    the memory node ships ``tables x D`` bytes to the CN instead of
+    ``rows x D``.  The kernel works in (T, B, ·) layout; the wrapper
+    transposes the indices in and the pooled output back.
 
-    Slots accumulate in ascending order, the same order the fused
-    CN-side bag revisits its output block, so fp32 results are bitwise
-    identical to ``embedding_bag_fused_flat`` and to
+    Slots accumulate in ascending order, the same order as the fused
+    CN-side bag, so fp32 results are bitwise identical to
+    ``embedding_bag_fused_flat`` and to
     ``kernels.ref.embedding_bag_seq_ref`` (tests pin this).
     """
-    Rtot, D = flat_table.shape
-    B, T, P = idx.shape
-
-    def table_map(t, b, idx_ref, off_ref):
-        return 0, 0                     # shard buffer resident on the node
-
-    def out_map(t, b, idx_ref, off_ref):
-        return b, t, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, B),
-        in_specs=[pl.BlockSpec((Rtot, D), table_map)],
-        out_specs=pl.BlockSpec((1, 1, D), out_map),
-    )
-    return pl.pallas_call(
-        functools.partial(_nmp_kernel, pool=P),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, D), jnp.float32),
-        interpret=interpret,
-    )(idx, offsets, flat_table)
+    out = _shard_call(_nmp_kernel, flat_table, offsets,
+                      jnp.transpose(idx, (1, 0, 2)), interpret)
+    return jnp.transpose(out, (1, 0, 2))
 
 
 def embedding_bag_nmp(tables: jax.Array, idx: jax.Array,

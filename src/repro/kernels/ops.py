@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the Pallas body
-executes as traced JAX); on TPU pass interpret=False (the default flips
-on TPU backends).
+``interpret=None`` picks the mode from the default backend: interpret
+mode on ``cpu`` (the Pallas body executes as traced JAX — the tests'
+setting), compiled Mosaic kernels on ``tpu``.  Any other backend is an
+error: there is no silent fallback that would hide a missing chip.
 """
 from __future__ import annotations
 
@@ -17,7 +18,11 @@ from repro.kernels import flash_decode as _fd
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run compiled on tpu or "
+                           f"interpreted on cpu, not on {backend!r}")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -28,7 +28,7 @@ import sys
 
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.data.queries import QueryDist, dlrm_request_stream
 from repro.models import registry
 from repro.serving.autoscaler import Autoscaler, AutoscalerConfig
@@ -186,6 +186,7 @@ def main(argv=None):
     p.add_argument("--no-kernel", dest="use_kernel", action="store_false",
                    default=True)
     args = p.parse_args(argv)
+    compile_cache.enable()
 
     if args.scenario:
         spec = ScenarioSpec.load(args.scenario)

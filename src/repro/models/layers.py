@@ -236,8 +236,6 @@ def context_parallel_attention(q, k, v, mesh, *, causal: bool = True,
     q: (B,S,H,D) logically global; k/v: (B,T,Hkv,D). Returns (B,S,H,D)
     sharded on S over `axis`.
     """
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
     B, S, H, D = q.shape
     s_loc = S // n
@@ -249,12 +247,12 @@ def context_parallel_attention(q, k, v, mesh, *, causal: bool = True,
             q_loc, k, v, causal=causal, q_offset=off,
             q_block=min(q_block, s_loc), kv_block=min(kv_block, k.shape[1]))
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, axis, None, None), P(bspec, None, None, None),
                   P(bspec, None, None, None)),
         out_specs=P(bspec, axis, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -335,8 +333,6 @@ def sharded_decode_attention(q, k_cache, v_cache, k_new, v_new, pos,
     shard_map with shard-local partial softmax + one psum of (o, l, m) —
     the Fsum pattern.
     """
-    from jax.experimental.shard_map import shard_map
-
     T = k_cache.shape[1]
     n_shards = mesh.shape[axis]
     t_loc = T // n_shards
@@ -359,11 +355,11 @@ def sharded_decode_attention(q, k_cache, v_cache, k_new, v_new, pos,
         return (combine_partials(o, l, m, axis),)
 
     qspec = P(bspec, None, None)
-    (out,) = shard_map(
+    (out,) = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(qspec, cspec, cspec, P()),
         out_specs=(qspec,),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, pos)
     return out, k_cache, v_cache
 

@@ -19,7 +19,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
@@ -142,13 +141,13 @@ def moe_apply(p, x, cfg, *, capacity_factor: Optional[float] = None):
             return _moe_local(x2d, w, ids, wg, wu, wo, e_off=e_off,
                               E_loc=E_loc, capacity=cap, cfg=cfg, axis="model")
 
-        y = shard_map(
+        y = jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(bspec, None), P(bspec, None), P(bspec, None),
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=P(bspec, None),
-            check_rep=False,
+            check_vma=False,
         )(x2d, w, ids, p["wi_gate"], p["wi_up"], p["wo"])
     else:
         cap = max(8, int((T_tok * m.top_k / m.num_experts) * capacity_factor))

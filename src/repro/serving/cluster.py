@@ -273,6 +273,12 @@ class ClusterStats:
     events: List["EventRecord"] = field(default_factory=list)
 
 
+def dense_step(model):
+    """The CN's jitted DenseNet step: (params, dense, pooled) -> CTR."""
+    return jax.jit(lambda p, d, pooled: jax.nn.sigmoid(
+        model.dense_forward(p, d, pooled)))
+
+
 class ClusterEngine:
     """Serve a DLRM over {n CN, m MN} with replica-aware routing.
 
@@ -355,10 +361,7 @@ class ClusterEngine:
             model.cfg, UnitSpec(self.n_cn, self.cfg.cn_type,
                                 self.m_mn, self.cfg.mn_type,
                                 mn_types=tuple(self.mn_types)))
-        self._dense_steps = [
-            jax.jit(lambda p, d, pooled, _m=m: jax.nn.sigmoid(
-                _m.dense_forward(p, d, pooled)))
-            for _, m, _ in self.fleet]
+        self._dense_steps = [dense_step(m) for _, m, _ in self.fleet]
         self._dense_step = self._dense_steps[0]
         # measured per-table hotness: feeds cache admission priorities
         # and re-allocation (reinit / replan) hot/cold classification.

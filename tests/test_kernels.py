@@ -224,3 +224,16 @@ def test_flash_decode_combine_matches_full():
           + np.asarray(o2) * np.asarray(c2)[..., None])
     np.testing.assert_allclose(og / np.maximum(lg, 1e-37)[..., None], want,
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_default_interpret_follows_backend(monkeypatch, backend, want):
+    """Interpret on cpu, compiled on tpu, and no silent fallback on any
+    other backend."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._default_interpret()
+    else:
+        assert ops._default_interpret() is want

@@ -25,7 +25,9 @@ from repro.train.optimizer import OptConfig
 from repro.distributed import sharding as shd
 
 out = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 
 # --- dense arch: sharded loss == single-device loss ---
 cfg = configs.get_reduced("llama3-8b").replace(
