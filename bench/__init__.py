@@ -1,0 +1,1 @@
+"""The chip benchmark of the served path (see ``run.py``)."""
