@@ -21,7 +21,6 @@ MODULES = [
     ("hot_row_cache", "benchmarks.bench_cache"),
     ("cluster_engine", "benchmarks.bench_cluster"),
     ("sla_traffic", "benchmarks.bench_sla"),
-    ("kernels", "benchmarks.bench_kernels"),
     ("roofline", "benchmarks.bench_roofline"),
 ]
 

@@ -17,6 +17,10 @@ them at published widths.
 
 Padding indices are negative: their DMA is skipped and the accumulate is
 predicated off (the old accumulator is selected, never ``acc + 0.0``).
+
+Each ``pallas_call`` carries a ``name=`` (``embedding_bag_fused``,
+``embedding_bag_nmp``, ``embedding_bag_1table``), the kernel's name in a
+device trace.
 """
 from __future__ import annotations
 
@@ -65,6 +69,7 @@ def embedding_bag_1table(table: jax.Array, idx: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
         interpret=interpret,
+        name="embedding_bag_1table",
     )(idx, table)
 
 
@@ -108,7 +113,7 @@ def _gather_pool(slot_row, base, table_hbm, rows, sems):
     return jax.lax.fori_loop(0, P, wait_add, jnp.zeros((1, D), jnp.float32))
 
 
-def _shard_call(kernel, flat_table, offsets, idx_blocked, interpret):
+def _shard_call(kernel, name, flat_table, offsets, idx_blocked, interpret):
     """pallas_call shared by both shard kernels: grid over the leading
     axis of ``idx_blocked`` (G, N, P), its (1, N, P) tile in SMEM per
     step, the shard in HBM, a (1, N, D) fp32 output block."""
@@ -129,6 +134,7 @@ def _shard_call(kernel, flat_table, offsets, idx_blocked, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, N, D), jnp.float32),
         interpret=interpret,
+        name=name,
     )(offsets, idx_blocked, flat_table)
 
 
@@ -159,7 +165,8 @@ def embedding_bag_fused_flat(flat_table: jax.Array, offsets: jax.Array,
     slot order — raw rows never return to HBM, only the pooled Fsum (the
     NMP insight, amortizing ONE kernel launch across the whole shard).
     """
-    return _shard_call(_fused_kernel, flat_table, offsets, idx, interpret)
+    return _shard_call(_fused_kernel, "embedding_bag_fused", flat_table,
+                       offsets, idx, interpret)
 
 
 def embedding_bag_fused(tables: jax.Array, idx: jax.Array,
@@ -208,7 +215,7 @@ def embedding_bag_nmp_flat(flat_table: jax.Array, offsets: jax.Array,
     ``embedding_bag_fused_flat`` and to
     ``kernels.ref.embedding_bag_seq_ref`` (tests pin this).
     """
-    out = _shard_call(_nmp_kernel, flat_table, offsets,
+    out = _shard_call(_nmp_kernel, "embedding_bag_nmp", flat_table, offsets,
                       jnp.transpose(idx, (1, 0, 2)), interpret)
     return jnp.transpose(out, (1, 0, 2))
 

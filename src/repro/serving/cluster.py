@@ -79,6 +79,14 @@ analytic unit model's stage times (G_P, scatter, G_S + gather from
 *measured* per-MN access/gather bytes at *per-node-type* bandwidths,
 G_D), so per-query latencies can be cross-validated against
 ``ServingUnitModel.stage_times`` and the DES (``validate_latency_model``).
+
+Wall-clock tracing: the served path marks its host steps with
+``jax.profiler.TraceAnnotation`` spans named ``repro.<step>`` (``_execute``
+here: ``route``, then per MN ``scatter``/``gather``/``account``, then
+``dense``; the rest in ``serving.timeline``).  They land in a profiler
+trace on the device's clock and cost about a microsecond each when no
+profiler runs.  ``bag_slots``/``bag_slots_valid`` count the index slots
+the bag kernels walk and the valid ones among them.
 """
 from __future__ import annotations
 
@@ -90,6 +98,7 @@ from typing import (TYPE_CHECKING, Dict, List, Optional, Sequence, Set,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import embedding_manager as em
 from repro.core import failure as fail_mod
@@ -274,9 +283,11 @@ class ClusterStats:
 
 
 def dense_step(model):
-    """The CN's jitted DenseNet step: (params, dense, pooled) -> CTR."""
-    return jax.jit(lambda p, d, pooled: jax.nn.sigmoid(
-        model.dense_forward(p, d, pooled)))
+    """The CN's jitted DenseNet step: (params, dense, pooled) -> CTR.
+    Its XLA module is named after the inner function: ``jit_dense_step``."""
+    def dense_step(p, d, pooled):
+        return jax.nn.sigmoid(model.dense_forward(p, d, pooled))
+    return jax.jit(dense_step)
 
 
 class ClusterEngine:
@@ -403,6 +414,10 @@ class ClusterEngine:
         self.retired_gather_bytes = 0.0
         self._mn_stage_max_sum = 0.0                # per-batch gating stage
         self._n_batches = 0
+        # index slots the bag kernels walk (B x tables x P per MN call,
+        # padding included) and the valid ones among them
+        self.bag_slots = 0
+        self.bag_slots_valid = 0
         # pipelined-execution introspection: the most recent serve()
         # call's per-batch trace and resource clocks (serving.pipeline)
         self.last_trace: List = []
@@ -809,8 +824,12 @@ class ClusterEngine:
         historical path bit-for-bit (the slice is the whole pool)."""
         off = self._tbl_off[model]
         Tm = self._tbl_count[model]
-        shards = em.shard_assignment(self.alloc, self.routing, self.T,
-                                     self.m_mn, task)
+        with TraceAnnotation("repro.route"):
+            shards = em.shard_assignment(self.alloc, self.routing, self.T,
+                                         self.m_mn, task)
+            # each MN's shard slice, restricted to the owning model's tables
+            calls = [(j, [t for t in tids if off <= t < off + Tm])
+                     for j, tids in enumerate(shards)]
         B = dense.shape[0]
         pooled = np.zeros((B, Tm, self.D), np.float32)
         mem_j = np.zeros(self.m_mn)
@@ -820,45 +839,54 @@ class ClusterEngine:
         batch_probes = 0
         batch_hit_bytes = 0.0
         self._last_scan = {}
-        for j, tids in enumerate(shards):
-            # restrict this MN's shard slice to the owning model's tables
-            mtids = [t for t in tids if off <= t < off + Tm]
+        for j, mtids in calls:
             if not mtids:
                 continue
             if j in self.dead:          # stale routing — never expected
                 raise LookupError(f"routing targets dead MN {j}")
-            cols = [t - off for t in mtids]
-            sub = idx[:, cols, :]
-            pooled[:, cols, :] = np.asarray(self._mn_pool(j, mtids, sub))
-            per_table = (sub >= 0).sum(axis=(0, 2))
-            self._last_scan[j] = [(int(t), float(pt) * row_b) for t, pt
-                                  in zip(mtids, per_table.tolist())]
-            self.hotness.update(mtids, per_table)
-            nvalid = int(per_table.sum())
-            if cache is not None and not self.mn_nmp[j]:
-                hits = self._cache_serve(cache, mtids, sub)
-                mem_j[j] = float(nvalid - hits) * row_b
-                gat_j[j] = mem_j[j]
-                self.cache_bytes_saved += float(hits) * row_b
-                # every tid in mtids belongs to `model`, so the whole
-                # shard's hits attribute to it without a per-tid split
-                self.fleet_cache_hits[model] += hits
-                self.fleet_cache_bytes_saved[model] += float(hits) * row_b
-                batch_probes += nvalid
-                batch_hit_bytes += float(hits) * row_b
-            elif self.mn_nmp[j]:
-                mem_j[j] = float(nvalid) * row_b
-                live_rows = int((sub >= 0).any(axis=(1, 2)).sum())
-                gat_j[j] = float(live_rows * len(mtids)) * row_b
-            else:
-                mem_j[j] = float(nvalid) * row_b
-                gat_j[j] = mem_j[j]
+            with TraceAnnotation("repro.scatter", mn=j,
+                                 nmp=int(self.mn_nmp[j]),
+                                 tables=len(mtids)):
+                cols = [t - off for t in mtids]
+                sub = idx[:, cols, :]
+                out = self._mn_pool(j, mtids, sub)
+            with TraceAnnotation("repro.gather", mn=j):
+                pooled[:, cols, :] = np.asarray(out)
+            with TraceAnnotation("repro.account", mn=j):
+                per_table = (sub >= 0).sum(axis=(0, 2))
+                self._last_scan[j] = [(int(t), float(pt) * row_b)
+                                      for t, pt in zip(mtids,
+                                                       per_table.tolist())]
+                self.hotness.update(mtids, per_table)
+                nvalid = int(per_table.sum())
+                self.bag_slots += sub.size
+                self.bag_slots_valid += nvalid
+                if cache is not None and not self.mn_nmp[j]:
+                    hits = self._cache_serve(cache, mtids, sub)
+                    mem_j[j] = float(nvalid - hits) * row_b
+                    gat_j[j] = mem_j[j]
+                    self.cache_bytes_saved += float(hits) * row_b
+                    # every tid in mtids belongs to `model`, so the whole
+                    # shard's hits attribute to it without a per-tid split
+                    self.fleet_cache_hits[model] += hits
+                    self.fleet_cache_bytes_saved[model] += (float(hits)
+                                                            * row_b)
+                    batch_probes += nvalid
+                    batch_hit_bytes += float(hits) * row_b
+                elif self.mn_nmp[j]:
+                    mem_j[j] = float(nvalid) * row_b
+                    live_rows = int((sub >= 0).any(axis=(1, 2)).sum())
+                    gat_j[j] = float(live_rows * len(mtids)) * row_b
+                else:
+                    mem_j[j] = float(nvalid) * row_b
+                    gat_j[j] = mem_j[j]
         # probe tags + hit rows stream from CN HBM on the virtual clock
         self._batch_cache_s = ((batch_probes * hw.CACHE_TAG_BYTES
                                 + batch_hit_bytes) / hw.CN_HBM_BW)
-        scores = np.asarray(self._dense_steps[model](
-            self._fleet_params[model], jnp.asarray(dense),
-            jnp.asarray(pooled)))
+        with TraceAnnotation("repro.dense"):
+            scores = np.asarray(self._dense_steps[model](
+                self._fleet_params[model], jnp.asarray(dense),
+                jnp.asarray(pooled)))
         return scores, mem_j, gat_j
 
     # ---------------------------------------------------------- serving

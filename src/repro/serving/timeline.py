@@ -67,6 +67,15 @@ the mid-stage failure scan in ``_next_fail``); scans straggling past
 onto replica buses (``_mn_plan``); and an optional ``SLAController``
 is fed every completion, its emitted ``Resize`` events joining the
 live queue via ``_enqueue``.
+
+**Wall-clock spans** (``jax.profiler.TraceAnnotation``, on the profiler's
+clock; separate from the virtual-clock ``BatchTrace``): ``repro.serve``
+wraps :meth:`TimelineDispatcher.run` and ``repro.batch`` each batch.
+Inside a batch the leaf spans never overlap: ``repro.assemble``
+(payload concat + pad), ``repro.clock`` (virtual-clock bookkeeping),
+``ClusterEngine._execute``'s ``repro.route``/``scatter``/``gather``/
+``account``/``dense``, and ``repro.complete`` (handing scores back);
+``repro.stats`` is the end-of-run ``ClusterStats`` fold.
 """
 from __future__ import annotations
 
@@ -75,6 +84,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import clocksan
 from repro.core import embedding_manager as em
@@ -532,10 +542,148 @@ class TimelineDispatcher:
         return mn_done, t_mn, gather
 
     def _run_batch(self, b: Batch, now: float) -> None:
+        with TraceAnnotation("repro.batch", bid=b.bid, rows=b.size) as span:
+            self._batch(b, now, span)
+
+    def _batch(self, b: Batch, now: float, span: TraceAnnotation) -> None:
         e = self.eng
         cfg = e.cfg
-        st = self.st
-        # assemble real rows from each member query's payload
+        with TraceAnnotation("repro.assemble"):
+            dense, idx = self._assemble(b)
+
+        with TraceAnnotation("repro.clock"):
+            st = self.st
+            scale = b.size / cfg.batch_size
+            # plan-then-commit: peek the pre stage without booking, inject
+            # any events due by mn_start, and only commit the pre on the
+            # CN that survives them.  (Booking up front would leave a
+            # phantom busy interval on a CN a shrink retires mid-window —
+            # and the superseded booking would advance free_at past the
+            # abort's start, so the FIFO clock could never take the
+            # charge back.)
+            task = self._route_cn(now)
+            cpu = self.cn_cpu[task]
+            pre_start = cpu.peek(now)
+            pre_done = pre_start + st.t_pre * scale  # reserve's exact chain
+            chain_ready = pre_done + st.t_comm_in * scale
+            mn_start = max(chain_ready, self.window.floor())
+
+            # MNs that died during G_P/scatter are gone before this
+            # batch's MN stage begins: re-route first, then execute
+            self._inject(mn_start)
+            # a CN shrink landing inside the G_P/scatter window may have
+            # retired the chosen CN: charge the superseded pre's in-flight
+            # prefix to the retired clock as an abort (mirroring
+            # _mn_abort) and hand the batch off to a survivor
+            while task >= len(self.cn_cpu):
+                t_ret = self._retire_s.get(id(cpu), mn_start)
+                cpu.charge_abort(pre_start, min(pre_done, t_ret), b.bid)
+                st = self.st
+                task = self._route_cn(now)
+                cpu = self.cn_cpu[task]
+                pre_start = cpu.peek(now)
+                pre_done = pre_start + st.t_pre * scale
+                chain_ready = pre_done + st.t_comm_in * scale
+                mn_start = max(chain_ready, self.window.floor())
+                self._inject(mn_start)
+            span.set_metadata(task=task)
+            st = self.st
+            cpu.book(now, pre_start, pre_done, b.bid)
+            self.window.wait_s += mn_start - chain_ready
+            # per-query queueing delay: arrival -> first batch admission
+            # (the instant its first part starts preprocessing).  Charged
+            # once per query, at the part that admits it.
+            for q, _ in b.parts:
+                if q.qid not in self.first_admit:
+                    self.first_admit[q.qid] = pre_start
+                    self.queue_waits.append(pre_start - self.arrival[q.qid])
+                    self.m_queue_waits.setdefault(b.model, []).append(
+                        pre_start - self.arrival[q.qid])
+        scores, mem_j, gat_j = e._execute(task, dense, idx, model=b.model)
+        with TraceAnnotation("repro.clock"):
+            stage_j = self._stage_account(mem_j, gat_j)
+            plan = self._mn_plan(task, mn_start, mem_j, gat_j,
+                                 e._batch_cache_s)
+
+        # a failure landing inside this batch's MN stage hits packets
+        # in flight: rebuild routing, re-issue on the survivors
+        reissued = 0
+        while True:
+            with TraceAnnotation("repro.clock"):
+                qi, nxt = self._next_fail()
+                if nxt is None or not (mn_start < nxt.time_s <= plan.end):
+                    break
+                self.queue.pop(qi)
+                t_fail, j = nxt.time_s, nxt.mn
+                if j >= e.m_mn:         # departed via an earlier shrink
+                    self._record(nxt, applied=False)
+                    continue
+                hit = mem_j[j] > 0
+                already = j in e.dead
+                e.fail_mn(j)
+                self._record(nxt, applied=not already)
+                if not hit:
+                    continue
+                # the aborted pass's traffic was already on the wire and
+                # the bus — charge the wasted bytes in full and each
+                # planned interval's in-flight prefix to its resource,
+                # then re-issue on the survivors
+                e.reissues += 1
+                reissued += 1
+                e.mn_access_bytes += mem_j
+                e.mn_gather_bytes += gat_j
+                e.mn_stage_s += stage_j
+                self._mn_abort(task, plan, t_fail, b.bid)
+            scores, mem_j, gat_j = e._execute(task, dense, idx,
+                                              model=b.model)
+            with TraceAnnotation("repro.clock"):
+                stage_j = self._stage_account(mem_j, gat_j)
+                mn_start = t_fail + cfg.mn_recovery_s
+                plan = self._mn_plan(task, mn_start, mem_j, gat_j,
+                                     e._batch_cache_s)
+        with TraceAnnotation("repro.clock"):
+            # an in-flight shard migration fair-shares the gather NIC
+            # path with this batch: each stream extends by the other's
+            # demand for the overlap
+            extra = 0.0
+            if mn_start < self.mig_end and gat_j.sum() > 0:
+                extra = float(gat_j.sum()) / hw.NIC_BW
+                self.mig_end += extra
+            mn_done, t_mn, gather_iv = self._mn_commit(task, plan, extra,
+                                                       b.bid)
+            self.window.complete(mn_done)
+            e.mn_access_bytes += mem_j
+            e.mn_gather_bytes += gat_j
+            e.mn_stage_s += stage_j
+            e._mn_stage_max_sum += t_mn
+            e._n_batches += 1
+        # keep admission priorities tracking the live workload even on
+        # an event-free run (deterministic: a pure function of the
+        # stream prefix served so far)
+        if e.caches and e._n_batches % 8 == 0:
+            with TraceAnnotation("repro.account"):
+                e._refresh_hot_tables()
+
+        with TraceAnnotation("repro.clock"):
+            d_start, done = self.cn_gpu[task].reserve(
+                mn_done, st.t_dense * scale, b.bid)
+            if done > self.last_done:
+                self.last_done = done
+            self.trace.append(BatchTrace(
+                bid=b.bid, task=task, size=b.size, pre=(pre_start, pre_done),
+                chain_ready=chain_ready, mn_start=mn_start,
+                scans=tuple((j, s, s + dur) for j, s, dur in plan.scans),
+                gather=gather_iv, mn_done=mn_done, dense=(d_start, done),
+                done=done, reissues=reissued,
+                qids=tuple(q.qid for q, _ in b.parts),
+                hedges=plan.hedges))
+
+        with TraceAnnotation("repro.complete"):
+            self._complete(b, scores, done)
+
+    def _assemble(self, b: Batch) -> Tuple[np.ndarray, np.ndarray]:
+        """The batch's real rows from each member query's payload, padded
+        to the batch size (dense rows with zeros, indices with -1)."""
         dense_rows, idx_rows = [], []
         for q, nrows in b.parts:
             c = self.row_cursor[q.qid]
@@ -544,129 +692,18 @@ class TimelineDispatcher:
             self.row_cursor[q.qid] = c + nrows
         dense = np.concatenate(dense_rows)
         idx = np.concatenate(idx_rows)
-        pad = cfg.batch_size - dense.shape[0]
+        pad = self.eng.cfg.batch_size - dense.shape[0]
         if pad > 0:
             dense = np.concatenate(
                 [dense, np.zeros_like(dense[:1]).repeat(pad, 0)])
             idx = np.concatenate(
                 [idx, -np.ones_like(idx[:1]).repeat(pad, 0)])
+        return dense, idx
 
-        scale = b.size / cfg.batch_size
-        # plan-then-commit: peek the pre stage without booking, inject
-        # any events due by mn_start, and only commit the pre on the CN
-        # that survives them.  (Booking up front would leave a phantom
-        # busy interval on a CN a shrink retires mid-window — and the
-        # superseded booking would advance free_at past the abort's
-        # start, so the FIFO clock could never take the charge back.)
-        task = self._route_cn(now)
-        cpu = self.cn_cpu[task]
-        pre_start = cpu.peek(now)
-        pre_done = pre_start + st.t_pre * scale  # reserve's exact chain
-        chain_ready = pre_done + st.t_comm_in * scale
-        mn_start = max(chain_ready, self.window.floor())
-
-        # MNs that died during G_P/scatter are gone before this batch's
-        # MN stage begins: re-route first, then execute
-        self._inject(mn_start)
-        # a CN shrink landing inside the G_P/scatter window may have
-        # retired the chosen CN: charge the superseded pre's in-flight
-        # prefix to the retired clock as an abort (mirroring _mn_abort)
-        # and hand the batch off to a survivor
-        while task >= len(self.cn_cpu):
-            t_ret = self._retire_s.get(id(cpu), mn_start)
-            cpu.charge_abort(pre_start, min(pre_done, t_ret), b.bid)
-            st = self.st
-            task = self._route_cn(now)
-            cpu = self.cn_cpu[task]
-            pre_start = cpu.peek(now)
-            pre_done = pre_start + st.t_pre * scale
-            chain_ready = pre_done + st.t_comm_in * scale
-            mn_start = max(chain_ready, self.window.floor())
-            self._inject(mn_start)
-        st = self.st
-        cpu.book(now, pre_start, pre_done, b.bid)
-        self.window.wait_s += mn_start - chain_ready
-        # per-query queueing delay: arrival -> first batch admission
-        # (the instant its first part starts preprocessing).  Charged
-        # once per query, at the part that admits it.
-        for q, _ in b.parts:
-            if q.qid not in self.first_admit:
-                self.first_admit[q.qid] = pre_start
-                self.queue_waits.append(pre_start - self.arrival[q.qid])
-                self.m_queue_waits.setdefault(b.model, []).append(
-                    pre_start - self.arrival[q.qid])
-        scores, mem_j, gat_j = e._execute(task, dense, idx, model=b.model)
-        stage_j = self._stage_account(mem_j, gat_j)
-        plan = self._mn_plan(task, mn_start, mem_j, gat_j,
-                             e._batch_cache_s)
-
-        # a failure landing inside this batch's MN stage hits packets
-        # in flight: rebuild routing, re-issue on the survivors
-        reissued = 0
-        while True:
-            qi, nxt = self._next_fail()
-            if nxt is None or not (mn_start < nxt.time_s <= plan.end):
-                break
-            self.queue.pop(qi)
-            t_fail, j = nxt.time_s, nxt.mn
-            if j >= e.m_mn:         # departed via an earlier shrink
-                self._record(nxt, applied=False)
-                continue
-            hit = mem_j[j] > 0
-            already = j in e.dead
-            e.fail_mn(j)
-            self._record(nxt, applied=not already)
-            if hit:
-                # the aborted pass's traffic was already on the wire
-                # and the bus — charge the wasted bytes in full and
-                # each planned interval's in-flight prefix to its
-                # resource, then re-issue on the survivors
-                e.reissues += 1
-                reissued += 1
-                e.mn_access_bytes += mem_j
-                e.mn_gather_bytes += gat_j
-                e.mn_stage_s += stage_j
-                self._mn_abort(task, plan, t_fail, b.bid)
-                scores, mem_j, gat_j = e._execute(task, dense, idx,
-                                                  model=b.model)
-                stage_j = self._stage_account(mem_j, gat_j)
-                mn_start = t_fail + cfg.mn_recovery_s
-                plan = self._mn_plan(task, mn_start, mem_j, gat_j,
-                                     e._batch_cache_s)
-        # an in-flight shard migration fair-shares the gather NIC path
-        # with this batch: each stream extends by the other's demand
-        # for the overlap
-        extra = 0.0
-        if mn_start < self.mig_end and gat_j.sum() > 0:
-            extra = float(gat_j.sum()) / hw.NIC_BW
-            self.mig_end += extra
-        mn_done, t_mn, gather_iv = self._mn_commit(task, plan, extra,
-                                                   b.bid)
-        self.window.complete(mn_done)
-        e.mn_access_bytes += mem_j
-        e.mn_gather_bytes += gat_j
-        e.mn_stage_s += stage_j
-        e._mn_stage_max_sum += t_mn
-        e._n_batches += 1
-        # keep admission priorities tracking the live workload even on
-        # an event-free run (deterministic: a pure function of the
-        # stream prefix served so far)
-        if e.caches and e._n_batches % 8 == 0:
-            e._refresh_hot_tables()
-
-        d_start, done = self.cn_gpu[task].reserve(
-            mn_done, st.t_dense * scale, b.bid)
-        if done > self.last_done:
-            self.last_done = done
-        self.trace.append(BatchTrace(
-            bid=b.bid, task=task, size=b.size, pre=(pre_start, pre_done),
-            chain_ready=chain_ready, mn_start=mn_start,
-            scans=tuple((j, s, s + dur) for j, s, dur in plan.scans),
-            gather=gather_iv, mn_done=mn_done, dense=(d_start, done),
-            done=done, reissues=reissued,
-            qids=tuple(q.qid for q, _ in b.parts),
-            hedges=plan.hedges))
-
+    def _complete(self, b: Batch, scores: np.ndarray, done: float) -> None:
+        """Hand the batch's scores back to its queries; a query whose
+        last rows these were completes, and its latency feeds the owning
+        model's SLA controller."""
         o = 0
         for q, nrows in b.parts:
             self.pieces[q.qid].append(scores[o:o + nrows])
@@ -723,6 +760,14 @@ class TimelineDispatcher:
                 self._run_batch(b, dl)
 
     def run(self) -> Tuple[List[Result], ClusterStats]:
+        with TraceAnnotation("repro.serve", requests=len(self.requests)):
+            self._dispatch()
+            with TraceAnnotation("repro.stats"):
+                return self._stats()
+
+    def _dispatch(self) -> None:
+        """Batch and serve the whole request stream, applying the event
+        queue in time order."""
         e = self.eng
         cfg = e.cfg
         # one ingress batcher per model in the stream (a single-model
@@ -736,7 +781,8 @@ class TimelineDispatcher:
         self.m_latencies: Dict[int, List[float]] = {}
         self.m_queue_waits: Dict[int, List[float]] = {}
         self.m_sla_actions: Dict[int, int] = {}
-        e._refresh_hot_tables()    # hotness measured by prior serving
+        with TraceAnnotation("repro.account"):
+            e._refresh_hot_tables()    # hotness measured by prior serving
         requests = self.requests
         self.payload = {r.rid: r.payload for r in requests}
         self.arrival = {r.rid: r.arrival for r in requests}
@@ -779,6 +825,11 @@ class TimelineDispatcher:
         # shape, and counters move.
         self._inject(math.inf)
 
+    def _stats(self) -> Tuple[List[Result], ClusterStats]:
+        """Fold the run into ``ClusterStats`` and hand back the results,
+        sorted by request id."""
+        e = self.eng
+        requests = self.requests
         # nothing completed reports nan, not a fabricated 0.0
         mean_lat, p50, p95, p99 = _lat_stats(self.latencies)
         qw_mean, _, _, qw_p99 = _lat_stats(self.queue_waits)
