@@ -86,7 +86,9 @@ here: ``route``, then per MN ``scatter``/``gather``/``account``, then
 ``dense``; the rest in ``serving.timeline``).  They land in a profiler
 trace on the device's clock and cost about a microsecond each when no
 profiler runs.  ``bag_slots``/``bag_slots_valid`` count the index slots
-the bag kernels walk and the valid ones among them.
+of the bag calls and the valid ones among them; ``bag_lanes`` counts the
+slots the kernels walk once the lane axis (tables of a fused call, bags
+of an NMP call) is padded to a multiple of 8.
 """
 from __future__ import annotations
 
@@ -105,6 +107,7 @@ from repro.core import failure as fail_mod
 from repro.core import hardware as hw
 from repro.core.hardware import NODE_TYPES
 from repro.core.serving_unit import ServingUnitModel, UnitSpec
+from repro.kernels.embedding_bag import pad_to_lanes
 from repro.serving.cache import CacheStats, RowCache
 from repro.serving.engine import Request, Result
 
@@ -414,10 +417,13 @@ class ClusterEngine:
         self.retired_gather_bytes = 0.0
         self._mn_stage_max_sum = 0.0                # per-batch gating stage
         self._n_batches = 0
-        # index slots the bag kernels walk (B x tables x P per MN call,
-        # padding included) and the valid ones among them
+        # index slots of the bag calls (B x tables x P per MN call,
+        # padding included), the valid ones among them, and the slots
+        # walked with the lane axis padded to 8 (bag_slots / bag_lanes
+        # is the lane fill)
         self.bag_slots = 0
         self.bag_slots_valid = 0
+        self.bag_lanes = 0
         # pipelined-execution introspection: the most recent serve()
         # call's per-batch trace and resource clocks (serving.pipeline)
         self.last_trace: List = []
@@ -861,6 +867,12 @@ class ClusterEngine:
                 nvalid = int(per_table.sum())
                 self.bag_slots += sub.size
                 self.bag_slots_valid += nvalid
+                nb, nt, npool = sub.shape
+                if self.mn_nmp[j]:
+                    nb = pad_to_lanes(nb)
+                else:
+                    nt = pad_to_lanes(nt)
+                self.bag_lanes += nb * nt * npool
                 if cache is not None and not self.mn_nmp[j]:
                     hits = self._cache_serve(cache, mtids, sub)
                     mem_j[j] = float(nvalid - hits) * row_b

@@ -318,3 +318,35 @@ def test_batcher_parts_conservation():
         for q, n in bt.parts:
             got[q.qid] = got.get(q.qid, 0) + n
     assert got == {i: s for i, s in enumerate(sizes)}
+
+
+@pytest.mark.parametrize("tables,mn_type,batch", [
+    (16, "ddr_mn", 64),      # T a multiple of 8: every lane filled
+    (13, "ddr_mn", 64),      # fused call pads T = 13 to 16 lanes
+    (13, "nmp_mn", 60),      # NMP call pads B = 60 to 64 lanes
+])
+def test_bag_lanes_count_lane_padding(tables, mn_type, batch):
+    """``bag_lanes`` counts the slots the bag kernels walk once the lane
+    axis (tables of a fused call, bags of an NMP call) is padded to 8;
+    it equals ``bag_slots`` when that axis is a multiple of 8."""
+    cfg = CFG.replace(dlrm=rm1.DLRMConfig(
+        num_tables=tables, rows_per_table=32, embed_dim=8, avg_pooling=5,
+        num_dense_features=8, bottom_mlp=(16, 8), top_mlp=(16, 1)))
+    model = DLRMModel(cfg)
+    rng = np.random.RandomState(3)
+    reqs = []
+    for i, size in enumerate((batch // 2, batch - batch // 2)):
+        b = dlrm_batch(cfg, size, rng)
+        reqs.append(Request(i, {"dense": b["dense"],
+                                "indices": b["indices"]}, size, 0.0))
+    eng = ClusterEngine(model, model.init(0), ClusterConfig(
+        n_cn=1, m_mn=1, batch_size=batch, n_replicas=1, mn_type=mn_type))
+    _, stats = eng.serve(reqs)
+    assert stats.completed == 2 and eng.batches_seen == 1
+    P = cfg.dlrm.avg_pooling
+    assert eng.bag_slots == batch * tables * P
+    lanes_b = -(-batch // 8) * 8 if mn_type == "nmp_mn" else batch
+    lanes_t = tables if mn_type == "nmp_mn" else -(-tables // 8) * 8
+    assert eng.bag_lanes == lanes_b * lanes_t * P
+    assert (eng.bag_lanes == eng.bag_slots) == (lanes_b * lanes_t
+                                                == batch * tables)

@@ -157,6 +157,66 @@ def test_embedding_bag_nmp_flat_shard_offsets():
     assert np.array_equal(out_n, want)
 
 
+def _lane_axis(B, T, n):
+    """Shape broadcasting a lane index over (B, T, P): the lane axis is
+    T when T == n (fused call), else B (NMP call)."""
+    return (1, n, 1) if T == n else (n, 1, 1)
+
+
+def _stale_lane_idx(rng, R, B, T, P):
+    """Lane axis of 32: groups 0 and 1 valid at every slot, groups 2 and
+    3 (the same two buffers) valid at slot 0 only, so a row left stale
+    from groups 0-1 would be added."""
+    idx = rng.randint(0, R, (B, T, P)).astype(np.int32)
+    lane = np.arange(32).reshape(_lane_axis(B, T, 32))
+    return np.where((lane < 16) | (np.arange(P) == 0), idx, -1)
+
+
+def _padding_group_idx(rng, R, B, T, P, padding_first):
+    """Lane axis of 16: one group all padding beside a group with one
+    valid lane, at slot 2 (``padding_first``: the padding group first)."""
+    idx = rng.randint(0, R, (B, T, P)).astype(np.int32)
+    lane = np.arange(16).reshape(_lane_axis(B, T, 16))
+    one = 11 if padding_first else 3
+    return np.where((lane == one) & (np.arange(P) == 2), idx, -1)
+
+
+@pytest.mark.parametrize("case,T,B,P", [
+    ("mixed", 7, 3, 6), ("mixed", 8, 3, 6), ("mixed", 9, 3, 6),
+    ("mixed", 17, 3, 6),
+    ("mixed", 2, 7, 6), ("mixed", 2, 8, 6), ("mixed", 2, 9, 6),
+    ("mixed", 2, 64, 6),
+    ("stale", 32, 2, 5), ("stale", 2, 32, 5),
+    ("padding_group_first", 16, 2, 5), ("padding_group_last", 16, 2, 5),
+    ("padding_group_first", 2, 16, 5), ("padding_group_last", 2, 16, 5),
+])
+def test_shard_bags_bitwise_at_lane_boundaries(case, T, B, P):
+    """The shard kernels pool eight bags per (8, D) tile (tables of a
+    fused call, bags of an NMP call): at lane counts around multiples
+    of 8, with a lane valid at a slot in one group and padding there in
+    a later group of the same buffer, and with an all-padding group,
+    both match the slot-order reference and each other bitwise."""
+    R, D = 48, 16
+    rng = np.random.RandomState(7)
+    tables = jnp.asarray(rng.randn(T, R, D), jnp.float32)
+    if case == "mixed":
+        idx = _mixed_pooling_idx(rng, R, B, T, P)
+    elif case == "stale":
+        idx = _stale_lane_idx(rng, R, B, T, P)
+    else:
+        idx = _padding_group_idx(rng, R, B, T, P,
+                                 case == "padding_group_first")
+    idx = jnp.asarray(idx)
+    want = np.asarray(ref.embedding_bag_seq_ref(tables, idx))
+    out_f = np.asarray(ops.embedding_bag_fused(tables, idx))
+    out_n = np.asarray(ops.embedding_bag_nmp(tables, idx))
+    assert out_f.shape == out_n.shape == (B, T, D)
+    assert np.array_equal(out_f, want)
+    assert np.array_equal(out_n, want)
+    if case != "mixed":
+        assert np.count_nonzero(want) > 0
+
+
 @pytest.mark.parametrize("B,H,Hkv,S,D,qb,kb", [
     (1, 4, 4, 128, 32, 64, 64),
     (2, 8, 2, 256, 32, 64, 128),

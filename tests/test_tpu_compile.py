@@ -1,8 +1,8 @@
 """Ahead-of-time compiles for a TPU v5e that is described, not attached.
 
 The served path's programs at the chip-share RM1's widths: both shard
-bag kernels at one MN's call shape (64 bags x 200 tables x 80 slots,
-D=128, over a 400-table shard) and the CN dense step at RM1's published
+bag kernels at one MN's call shapes (64 bags x 200, 320, 80 or 201
+tables x 80 slots, D=128, over a 400-table shard) and the CN dense step at RM1's published
 MLP widths.  A compile that passes here is what the v5e compiler accepts;
 it runs nothing, so it says nothing about results or times.
 
@@ -67,6 +67,24 @@ def test_bag_kernel_compiles_for_v5e(kernel, one_chip):
     # the kernel keeps its name= on the device, where a trace reads it
     assert f"%{kernel.removesuffix('_flat')}" in text
     assert compiled.out_info.shape == (B, T_MN, D)
+
+
+@pytest.mark.parametrize("t_mn", [320, 80, 201])
+@pytest.mark.parametrize("kernel", ["embedding_bag_fused_flat",
+                                    "embedding_bag_nmp_flat"])
+def test_bag_kernel_compiles_for_v5e_at_routed_shapes(kernel, t_mn,
+                                                       one_chip):
+    """The table counts a 2 DDR + 2 NMP pool routes to one MN (320 to an
+    NMP MN, 80 to a DDR MN), and 201, which the wrapper pads to 208
+    lanes for the fused call."""
+    D, R, P = CFG.embed_dim, CFG.rows_per_table, CFG.avg_pooling
+    fn = functools.partial(getattr(eb, kernel), interpret=False)
+    compiled = jax.jit(fn).lower(
+        _spec((SHARD_TABLES * R, D), jnp.float32, one_chip),
+        _spec((t_mn,), jnp.int32, one_chip),
+        _spec((B, t_mn, P), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (B, t_mn, D)
 
 
 def test_dense_step_compiles_for_v5e(one_chip):
