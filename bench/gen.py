@@ -2,9 +2,10 @@
 file and ``--seed``.
 
 The samplers are copies of ``repro.data.queries`` (``QueryDist.sample``,
-the ``poisson`` and ``bursty`` branches of ``ArrivalProcess``, the index
-and pooling-length draws of ``dlrm_batch``, ``zipf_indices``), kept here so
-that no change to the program can move the yardstick.  They take a
+the ``poisson`` and ``bursty`` branches of ``ArrivalProcess``,
+``zipf_indices``), kept here so that no change to the program can move
+the yardstick; a family draws its payload rows
+(``families/<family>.make_rows``: DLRM's copies ``dlrm_batch``).  They take a
 ``numpy.random.Generator``, which accepts any whole-number seed, where the
 originals take a ``RandomState`` (seeds below 2**32 only); uniform rows are
 drawn directly instead of hashing uniform raw ids, which gives the same
@@ -20,7 +21,7 @@ window holds or when they arrive.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Iterator, List, Tuple
+from typing import Dict, Iterator, List
 
 import numpy as np
 
@@ -83,25 +84,6 @@ def zipf_indices(rng: np.random.Generator, shape, num_rows: int,
     cdf /= cdf[-1]
     return np.searchsorted(cdf, rng.uniform(size=shape),
                            side="right").astype(np.int32)
-
-
-def make_rows(cfg: Dict, n: int, rng: np.random.Generator,
-              pooling_sigma: float, alpha: float
-              ) -> Tuple[np.ndarray, np.ndarray]:
-    """``n`` candidate rows for a DLRM configuration: dense features
-    (n, F) fp32 and indices (n, T, P) int32, each bag holding a lognormal
-    number of valid rows (median 0.7 P) and -1 after them (``dlrm_batch``)."""
-    T, P, R = cfg["num_tables"], cfg["avg_pooling"], cfg["rows_per_table"]
-    dense = rng.standard_normal((n, cfg["num_dense_features"]),
-                                dtype=np.float32)
-    if alpha > 0.0:
-        idx = zipf_indices(rng, (n, T, P), R, alpha)
-    else:
-        idx = rng.integers(0, R, size=(n, T, P), dtype=np.int32)
-    lens = np.clip(rng.lognormal(np.log(max(P * 0.7, 1.0)), pooling_sigma,
-                                 size=(n, T)), 1, P)
-    idx[np.arange(P)[None, None, :] >= lens[..., None]] = -1
-    return dense, idx
 
 
 # ---------------------------------------------------------------- plans

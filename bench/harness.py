@@ -20,11 +20,13 @@ each request from its scheduled arrival to the return of its call;
 """
 from __future__ import annotations
 
+import functools
 import gc
 import importlib
 import importlib.util
 import json
 import math
+import numbers
 import shutil
 import sys
 import tempfile
@@ -36,17 +38,16 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from bench import gen
+from bench import layers
 from bench import trace as trace_mod
+from bench.errors import BenchError
+from bench.families import family
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 REF_BLOCK = 32          # rows per reference call
 SAMPLE_ROWS = 512       # rows the correctness sample aims for
 SPANS = ("bench.serve", "bench.wait", "bench.assemble")
-
-
-class BenchError(Exception):
-    """A run that cannot give a result (no chip, unknown cell, ...)."""
 
 
 # ------------------------------------------------------------------ cells
@@ -124,21 +125,6 @@ def load_reader(metric: str, bench_dir: Path = BENCH) -> Callable:
 
 
 # ----------------------------------------------------------- system setup
-def model_config(cfg: Dict):
-    from repro.configs.base import DLRMConfig, ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dlrm", num_layers=0, num_heads=0,
-        num_kv_heads=0, d_ff=0, vocab_size=0, d_model=cfg["embed_dim"],
-        dlrm=DLRMConfig(
-            num_tables=cfg["num_tables"],
-            rows_per_table=cfg["rows_per_table"],
-            embed_dim=cfg["embed_dim"], avg_pooling=cfg["avg_pooling"],
-            num_dense_features=cfg["num_dense_features"],
-            bottom_mlp=tuple(cfg["bottom_mlp"]),
-            top_mlp=tuple(cfg["top_mlp"]),
-            interaction_proj=cfg["interaction_proj"]))
-
-
 def build_engine(cell: Cell, params):
     from repro.models import registry
     from repro.serving.cluster import ClusterConfig, ClusterEngine
@@ -148,8 +134,9 @@ def build_engine(cell: Cell, params):
                        n_replicas=p["n_replicas"],
                        mn_types=tuple(p["mn_types"]),
                        cache_mb=p["cache_mb"])
-    return ClusterEngine(registry.build(model_config(cell.config)), params,
-                         cc)
+    return ClusterEngine(
+        registry.build(family(cell.config).model_config(cell.config)),
+        params, cc)
 
 
 class CompileCounter:
@@ -187,10 +174,9 @@ class Payloads:
     """The seeded pool of distinct candidate rows requests are cut from."""
 
     def __init__(self, cell: Cell, seed: int):
-        t = cell.traffic
-        self.dense, self.idx = gen.make_rows(
-            cell.config, t["pool_rows"], gen.rng_for(seed, 0),
-            t["pooling_sigma"], t["alpha"])
+        cfg, t = cell.config, cell.traffic
+        self.dense, self.idx = family(cfg).make_rows(
+            cfg, t["pool_rows"], gen.rng_for(seed, 0), t)
 
     def request(self, rid: int, part: gen.Part):
         from repro.serving.engine import Request
@@ -211,6 +197,7 @@ class Window:
     parts: Dict[int, gen.Part] = field(default_factory=dict)
     done: Dict[int, Tuple[float, np.ndarray]] = field(default_factory=dict)
     serve_s: float = 0.0          # host seconds inside serve() calls
+    longest_s: float = 0.0        # the longest serve() call
     calls: int = 0
     t_end: float = 0.0            # last completion, seconds from start
     late_s: List[float] = field(default_factory=list)
@@ -236,6 +223,7 @@ def _serve(engine, reqs, win: Window, t0: float) -> None:
         results, _ = engine.serve(reqs)
         e = time.perf_counter()
     win.serve_s += e - s
+    win.longest_s = max(win.longest_s, e - s)
     win.calls += 1
     win.record(results, e - t0)
 
@@ -296,11 +284,6 @@ def warm_up(engine, payloads: Payloads, cell: Cell,
         if compiles.n == before:
             return call
     raise BenchError("warm-up still compiles after 4 calls")
-
-
-def nearest_rank(values: List[float], q: float) -> float:
-    s = sorted(values)
-    return s[max(0, math.ceil(q * len(s)) - 1)]
 
 
 # ------------------------------------------------------------ correctness
@@ -396,9 +379,32 @@ class Reading:
     trace: Optional[trace_mod.Trace] = None
     lo: float = 0.0           # the traced window on the trace's clock, ns
     hi: float = 0.0
+    # change of each of the engine's integer counters over the window
+    counters: Dict[str, int] = field(default_factory=dict)
+    # each completed request's latency, seconds, where requests arrive on
+    # a schedule (open traffic)
+    latency_s: List[float] = field(default_factory=list)
 
     def modules(self) -> Dict[str, float]:
         return trace_mod.module_seconds(self.trace, self.lo, self.hi)
+
+    @functools.cached_property
+    def spans(self) -> List[Tuple[str, float, float]]:
+        """The program's spans (``repro.*``) inside the traced window, as
+        (name, start, end), by start."""
+        if self.trace is None:
+            return []
+        return [(n, s, e) for n, s, e in self.trace.spans
+                if n.startswith(trace_mod.PROGRAM_PREFIX)
+                and self.lo <= s and e <= self.hi]
+
+
+def counters(engine) -> Dict[str, int]:
+    """Every public integer attribute of ``engine``: its counters (and
+    its sizes, which a window leaves unchanged)."""
+    return {k: int(v) for k, v in vars(engine).items()
+            if not k.startswith("_") and isinstance(v, numbers.Integral)
+            and not isinstance(v, bool)}
 
 
 def valid_slots(win: Window, payloads: Payloads) -> int:
@@ -477,6 +483,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         batches0, compiles0 = engine.batches_seen, compiles.n
         tdir = None
         if trace:
+            counts0 = counters(engine)
             tdir = Path(tempfile.mkdtemp(prefix="bench-trace-"))
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
@@ -488,6 +495,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             win = runner(engine, payloads, cell, seed, seconds)
         if trace:
             jax.profiler.stop_trace()
+            counts = {k: v - counts0[k] for k, v in counters(engine).items()
+                      if k in counts0}
         window_compiles = compiles.n - compiles0
         batches = engine.batches_seen - batches0
     mem = memory_peak(devices)
@@ -502,17 +511,19 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     rows = win.rows_done
     e2e = {"rows_per_s": rows / win.t_end if win.t_end > 0 else 0.0,
            "setup_s": setup_s}
+    lat = []
     if win.late_s:
         lat = [t - win.parts[r].arrival for r, (t, _) in win.done.items()]
-        e2e["p50_ms"] = 1e3 * nearest_rank(lat, 0.5)
-        e2e["p90_ms"] = 1e3 * nearest_rank(lat, 0.9)
         _log(f"generator late by median {1e3 * np.median(win.late_s):.3f} "
-             f"ms, max {1e3 * max(win.late_s):.3f} ms")
+             f"ms, max {1e3 * max(win.late_s):.3f} ms; latency p50 "
+             f"{1e3 * layers.nearest_rank(lat, 0.5)!r} ms, p90 "
+             f"{1e3 * layers.nearest_rank(lat, 0.9)!r} ms")
     slots = valid_slots(win, payloads)
     _log(f"window: {attempted} requests, {rows} rows, {slots} valid "
          f"slots, {batches} batches, {win.calls} serve calls, "
          f"{1e3 * win.serve_s / max(batches, 1):.3f} ms in serve() per "
-         f"batch, {win.t_end:.3f} s, "
+         f"batch, longest call {1e3 * win.longest_s:.3f} ms, "
+         f"{win.t_end:.3f} s, "
          + ", ".join(f"{k} {v!r}" for k, v in e2e.items()))
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -530,7 +541,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             raise BenchError("the trace holds no bench.window span")
         lo, hi = spans[0]
         reading = Reading(cell, peak, rows, slots, batches, win.serve_s,
-                          win.t_end, tr, lo, hi)
+                          win.t_end, tr, lo, hi, counts, lat)
         for m in cell.per_layer:
             v = load_reader(m["name"])(reading)
             if v is not None:

@@ -4,12 +4,23 @@ nothing to read, never 0 for a share of a roofline or of a peak.
 Shares are in percent."""
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import bisect
+import math
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from bench import flops
 from bench import trace as tr
+from bench.families import family
 
 BAG_MODULE = "jit_embedding_bag"
+# the program's leaf spans, grouped by the layer whose device-idle time
+# they hold; the leaves of one batch never overlap
+GROUPS: Dict[str, Tuple[str, ...]] = {
+    "scatter_idle_ms": ("repro.route", "repro.scatter"),
+    "gather_idle_ms": ("repro.gather", "repro.dense"),
+    "bookkeeping_idle_ms": ("repro.assemble", "repro.account",
+                            "repro.clock", "repro.complete", "repro.stats"),
+}
 
 
 def _split_modules(r) -> Tuple[float, float]:
@@ -30,7 +41,8 @@ def bag_roofline(r) -> Optional[float]:
     bag, _ = _split_modules(r)
     if bag <= 0 or r.rows <= 0:
         return None
-    need = sum(flops.bag_bytes(r.cell.config, r.rows, r.valid_slots).values())
+    cfg = r.cell.config
+    need = sum(family(cfg).bag_bytes(cfg, r.rows, r.valid_slots).values())
     return 100.0 * need / r.peak["hbm_bytes_per_s"] / bag
 
 
@@ -38,9 +50,10 @@ def dense_bounds(r) -> Dict[str, float]:
     """Least seconds of the window's dense work at the peak FLOP rate and
     at peak HBM bandwidth (weights read once per batch)."""
     cfg = r.cell.config
-    fl = r.rows * sum(flops.dense_flops_per_row(cfg).values())
-    by = (r.batches * flops.dense_weight_bytes(cfg)
-          + flops.dense_activation_bytes(cfg, r.rows))
+    fam = family(cfg)
+    fl = r.rows * sum(fam.dense_flops_per_row(cfg).values())
+    by = (r.batches * fam.dense_weight_bytes(cfg)
+          + fam.dense_activation_bytes(cfg, r.rows))
     return {"flops": fl / r.peak["bf16_flops_per_s"],
             "bytes": by / r.peak["hbm_bytes_per_s"]}
 
@@ -82,3 +95,67 @@ def batch_fill(r) -> Optional[float]:
     if r.batches <= 0:
         return None
     return 100.0 * r.rows / (r.batches * r.cell.pool["batch_size"])
+
+
+def nearest_rank(values: Sequence[float], q: float) -> float:
+    s = sorted(values)
+    return s[max(0, math.ceil(q * len(s)) - 1)]
+
+
+def latency_ms(r, q: float) -> Optional[float]:
+    """The ``q`` quantile (nearest rank) of the completed requests'
+    latencies, from scheduled arrival to the return of their serve()
+    call."""
+    if not r.latency_s:
+        return None
+    return 1e3 * nearest_rank(r.latency_s, q)
+
+
+def bag_slot_fill(r) -> Optional[float]:
+    """Valid bag slots over the slots the bag kernels walked in the window
+    (the engine's counters)."""
+    slots = r.counters.get("bag_slots", 0)
+    if slots <= 0:
+        return None
+    return 100.0 * r.counters["bag_slots_valid"] / slots
+
+
+# ------------------------------------------------- device idle in spans
+def within(merged: Sequence[tr.Interval], lo: float, hi: float
+           ) -> List[tr.Interval]:
+    """The parts of ``merged`` (sorted, disjoint) inside ``[lo, hi]``; a
+    bisection finds the first, so many short windows over a long trace
+    stay cheap."""
+    out = []
+    k = max(0, bisect.bisect_right(merged, (lo, float("inf"))) - 1)
+    for s, e in merged[k:]:
+        if s >= hi:
+            break
+        if e > lo:
+            out.append((max(s, lo), min(e, hi)))
+    return out
+
+
+def covered(merged: Sequence[tr.Interval], lo: float, hi: float) -> float:
+    """Length of ``[lo, hi]`` that ``merged`` (sorted, disjoint) covers."""
+    return sum(e - s for s, e in within(merged, lo, hi))
+
+
+def idle_s(t: tr.Trace, windows: Sequence[tr.Interval]) -> float:
+    """Seconds inside the union of ``windows`` with no operation on the
+    device, averaged over the devices traced (all of it without one)."""
+    inside = tr.merge(windows)
+    held = sum(e - s for s, e in inside)
+    busy = (sum(covered(b, s, e) for b in t.busy for s, e in inside)
+            / len(t.busy) if t.busy else 0.0)
+    return (held - busy) / 1e9
+
+
+def span_idle_ms(r, group: str) -> Optional[float]:
+    """Milliseconds per batch with no operation on the device inside the
+    union of the window's program spans of ``GROUPS[group]``."""
+    names = GROUPS[group]
+    windows = [(s, e) for n, s, e in r.spans if n in names]
+    if not windows or r.batches <= 0:
+        return None
+    return 1e3 * idle_s(r.trace, windows) / r.batches

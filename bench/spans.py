@@ -3,42 +3,36 @@ served path's own spans in a kept profiler trace.
 
 The program (``repro.serving``) marks each host step of a batch with a
 ``jax.profiler.TraceAnnotation`` named ``repro.<step>``; the leaf spans of
-one batch never overlap.  This module groups the leaves by layer and gives,
-per batch, the device-idle milliseconds inside each group, on the
+one batch never overlap.  This module gives, per batch, the device-idle
+milliseconds inside each group of leaves (``layers.GROUPS``), on the
 definition of the harness's ``host_ms_per_batch``: span time less the
-device-busy time inside it.  It also names each long idle gap by the span
-that holds most of it.
+device-busy time inside it, and what no leaf holds.  It also names each
+long idle gap by the span that holds most of it.
 
     python3 bench/run.py --workload rm1.backlog --seed 7 --seconds 51 \
         --trace 1 --keep-trace out/rm1.backlog.xplane.pb
     python3 bench/spans.py out/rm1.backlog.xplane.pb
 
-prints one JSON object per trace.  The harness's result line does not
-carry these numbers: its reduction (``bench/trace.py``) keeps the
-``bench.*`` spans alone.
+prints one JSON object per trace.  The result line of a ``--trace 1`` run
+carries the groups' numbers as per-layer metrics (``*_idle_ms.*``), read
+by ``layers.span_idle_ms`` over the window; this split adds the remainder
+and the named gaps.
 """
 from __future__ import annotations
 
-import bisect
 import json
 import sys
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 if __package__ in (None, ""):
     sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from bench import trace as tr  # noqa: E402
+from bench.layers import GROUPS, covered, idle_s, within  # noqa: E402
 
-PREFIX = "repro."
-# the leaves, grouped by the layer whose device-idle time they hold
-GROUPS: Dict[str, Tuple[str, ...]] = {
-    "scatter_idle_ms": ("repro.route", "repro.scatter"),
-    "gather_idle_ms": ("repro.gather", "repro.dense"),
-    "bookkeeping_idle_ms": ("repro.assemble", "repro.account",
-                            "repro.clock", "repro.complete", "repro.stats"),
-}
-LEAF_SPANS = tuple(s for g in GROUPS.values() for s in g)
+LEAF_SPANS = tuple(n for g in GROUPS.values() for n in g)
+
 # spans that enclose the leaves, innermost first, then the harness's
 ENCLOSING = (("repro.batch", "repro.serve"),
              ("bench.serve", "bench.wait", "bench.assemble"))
@@ -49,55 +43,13 @@ Span = Tuple[str, float, float, Dict]
 def program_spans(profile) -> List[Span]:
     """Every ``repro.*`` event of the host plane as (name, start, end,
     metadata), by start."""
-    out: List[Span] = []
-    for plane in profile.planes:
-        if plane.name != tr.HOST_PLANE:
-            continue
-        for line in plane.lines:
-            out += [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
-                    for ev in line.events if ev.name.startswith(PREFIX)]
-    return sorted(out, key=lambda x: x[1])
+    return sorted(((ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+                   for ev in tr.host_events(profile, (tr.PROGRAM_PREFIX,))),
+                  key=lambda x: x[1])
 
 
-def load(path: Path) -> tr.Trace:
-    """The harness's reduction of the trace at ``path``, with the
-    program's spans added to its spans."""
-    from jax.profiler import ProfileData
-    profile = ProfileData.from_file(str(path))
-    t = tr.from_profile(profile)
-    t.spans += [(n, s, e) for n, s, e, _ in program_spans(profile)]
-    t.spans.sort(key=lambda x: x[1])
-    return t
-
-
-def within(merged: Sequence[tr.Interval], lo: float, hi: float
-           ) -> List[tr.Interval]:
-    """The parts of ``merged`` (sorted, disjoint) inside ``[lo, hi]``; a
-    bisection finds the first, so many short windows over a long trace
-    stay cheap."""
-    out = []
-    k = max(0, bisect.bisect_right(merged, (lo, float("inf"))) - 1)
-    for s, e in merged[k:]:
-        if s >= hi:
-            break
-        if e > lo:
-            out.append((max(s, lo), min(e, hi)))
-    return out
-
-
-def covered(merged: Sequence[tr.Interval], lo: float, hi: float) -> float:
-    """Length of ``[lo, hi]`` that ``merged`` (sorted, disjoint) covers."""
-    return sum(e - s for s, e in within(merged, lo, hi))
-
-
-def idle_s(t: tr.Trace, windows: Sequence[tr.Interval]) -> float:
-    """Seconds inside the union of ``windows`` with no operation on the
-    device, averaged over the devices traced (all of it without one)."""
-    inside = tr.merge(windows)
-    held = sum(e - s for s, e in inside)
-    busy = (sum(covered(b, s, e) for b in t.busy for s, e in inside)
-            / len(t.busy) if t.busy else 0.0)
-    return (held - busy) / 1e9
+# the harness's reduction keeps the program's spans
+load = tr.load
 
 
 def named_gaps(t: tr.Trace, lo: float, hi: float, top: int = 10

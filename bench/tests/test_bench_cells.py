@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench import gen, harness
+from bench.families import family
 
 SPEC = harness.load_spec()
 
@@ -109,8 +110,11 @@ def test_backlog_chunks_hold_exact_rows():
 def test_payload_rows_seeded():
     cfg = json.loads((harness.BENCH / "configs" / "rm1-chip.json")
                      .read_text())
-    d1, i1 = gen.make_rows(cfg, 4, gen.rng_for(2 ** 31 + 1, 0), 0.3, 0.0)
-    d2, i2 = gen.make_rows(cfg, 4, gen.rng_for(2 ** 31 + 1, 0), 0.3, 0.0)
+    traffic = {"pooling_sigma": 0.3, "alpha": 0.0}
+    d1, i1 = family(cfg).make_rows(cfg, 4, gen.rng_for(2 ** 31 + 1, 0),
+                                   traffic)
+    d2, i2 = family(cfg).make_rows(cfg, 4, gen.rng_for(2 ** 31 + 1, 0),
+                                   traffic)
     assert np.array_equal(d1, d2) and np.array_equal(i1, i2)
     assert i1.shape == (4, 800, 80) and i1.dtype == np.int32
     assert i1.max() < cfg["rows_per_table"] and i1.min() >= -1
@@ -118,3 +122,17 @@ def test_payload_rows_seeded():
     valid = i1 >= 0
     assert valid[..., 0].all()
     assert (np.diff(valid.astype(int), axis=-1) <= 0).all()
+
+
+@pytest.mark.parametrize("q", [50, 90])
+def test_latency_readers_take_nearest_rank(q):
+    """``p50_ms.open``/``p90_ms.open`` read the nearest-rank percentile of
+    every completed request's latency, and nothing where the run timed no
+    request (backlog traffic)."""
+    cell = harness.resolve("rm1.open")
+    read = harness.load_reader(f"p{q}_ms.open")
+    lat = [0.001 * (i + 1) for i in range(40)][::-1]
+    reading = harness.Reading(cell, {}, rows=1, valid_slots=1, batches=1,
+                              serve_s=1.0, window_s=1.0, latency_s=lat)
+    assert read(reading) == pytest.approx({50: 20.0, 90: 36.0}[q])
+    assert read(harness.Reading(cell, {}, 1, 1, 1, 1.0, 1.0)) is None

@@ -6,7 +6,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import flops, gen
+from bench import flops
+from bench.families import family
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
@@ -17,13 +18,14 @@ def _cfg(name):
 
 def _mean_valid(cfg):
     """Mean valid slots per bag of the generator's pooling lengths."""
-    _, idx = gen.make_rows(cfg, 64, np.random.default_rng(0), 0.3, 0.0)
+    _, idx = family(cfg).make_rows(cfg, 64, np.random.default_rng(0),
+                                   {"pooling_sigma": 0.3, "alpha": 0.0})
     return float((idx >= 0).sum()) / (64 * cfg["num_tables"])
 
 
 def test_rm1_flops_per_row():
     cfg = _cfg("rm1-chip")
-    parts = flops.dense_flops_per_row(cfg)
+    parts = family(cfg).dense_flops_per_row(cfg)
     valid = _mean_valid(cfg) * cfg["num_tables"]
     assert parts["proj"] == pytest.approx(13.1e6, rel=0.01)
     assert parts["top_mlp"] == pytest.approx(7.9e6, rel=0.01)
@@ -41,8 +43,8 @@ RM2 = dict(_cfg("rm1-chip"), name="rm2", num_tables=400, avg_pooling=40,
 def test_rm2_dense_weights_and_flops():
     cfg = RM2
     valid = _mean_valid(cfg) * cfg["num_tables"]
-    assert flops.dense_weight_bytes(cfg) == pytest.approx(1.91e9, rel=0.005)
-    assert flops.dense_flops_per_row(cfg)["top_mlp"] == pytest.approx(
+    assert family(cfg).dense_weight_bytes(cfg) == pytest.approx(1.91e9, rel=0.005)
+    assert family(cfg).dense_flops_per_row(cfg)["top_mlp"] == pytest.approx(
         945e6, rel=0.005)
     assert flops.model_flops(cfg, 1, valid) == pytest.approx(964e6,
                                                              rel=0.005)
@@ -51,7 +53,7 @@ def test_rm2_dense_weights_and_flops():
 def test_rm1_valid_row_bytes_per_full_batch():
     cfg = _cfg("rm1-chip")
     valid = _mean_valid(cfg) * cfg["num_tables"] * 64
-    got = flops.bag_bytes(cfg, 64, valid)
+    got = family(cfg).bag_bytes(cfg, 64, valid)
     assert got["rows"] == pytest.approx(1.51e9, rel=0.01)
     assert got["indices"] == 64 * 800 * 80 * 4
     assert got["pooled"] == 64 * 800 * 128 * 4

@@ -222,20 +222,69 @@ def test_span_trace_gaps_named_by_program_spans(span_trace):
 
 
 def test_accepted_readers_on_span_trace():
-    """The program's spans leave the harness's own reduction as it was:
-    every per-layer reader of the cell reads the trace through it."""
+    """The harness's reduction keeps its own spans and the program's, and
+    every per-layer reader of the cell reads the trace through it, the
+    program's counters over the window given (8 batches of 64 rows, 800
+    tables, 80 slots a bag)."""
     t = tr.load(DATA / "rm1_nmp_backlog_spans_1s.xplane.pb")
-    assert {n for n, _, _ in t.spans} <= {"bench.window", "bench.serve",
-                                          "bench.wait", "bench.assemble"}
+    assert {n for n, _, _ in t.spans} == {
+        "bench.window", "bench.serve", "bench.assemble", "repro.serve",
+        "repro.batch", *spans.LEAF_SPANS}
     cell = harness.resolve("rm1.nmp-backlog")
     (lo, hi), = t.span("bench.window")
     serve_s = sum(e - s for s, e in t.span("bench.serve")) / 1e9
     reading = harness.Reading(
         cell, flops.peaks("TPU v5 lite"), rows=512, valid_slots=23550741,
         batches=8, serve_s=serve_s, window_s=(hi - lo) / 1e9, trace=t,
-        lo=lo, hi=hi)
+        lo=lo, hi=hi, counters={"bag_slots": 8 * 64 * 800 * 80,
+                                "bag_slots_valid": 23550741})
     for m in cell.per_layer:
         v = harness.load_reader(m["name"])(reading)
         assert v is not None and v > 0, m["name"]
         if m["unit"] == "%":
             assert v <= 100, (m["name"], v)
+
+
+def test_span_readers_match_split(span_trace):
+    """The result line's ``*_idle_ms`` readers give on the chip trace what
+    ``split`` gives on it, and ``bag_slot_fill`` the share of the counters
+    it is handed."""
+    got = spans.split(span_trace)
+    (lo, hi), = span_trace.span("bench.window")
+    reading = harness.Reading(
+        harness.resolve("rm1.nmp-backlog"), flops.peaks("TPU v5 lite"),
+        rows=512, valid_slots=23550741, batches=8, serve_s=0.0,
+        window_s=(hi - lo) / 1e9, trace=span_trace, lo=lo, hi=hi,
+        counters={"bag_slots": 32768000, "bag_slots_valid": 23550741})
+    for group in spans.GROUPS:
+        v = harness.load_reader(f"{group}.backlog")(reading)
+        assert v == pytest.approx(got[group], rel=1e-12), group
+        assert v == pytest.approx(layers.span_idle_ms(reading, group))
+    assert harness.load_reader("bag_slot_fill.backlog")(
+        reading) == pytest.approx(100 * 23550741 / 32768000)
+    # a reading that holds no spans or no counters reads nothing
+    bare = dataclasses.replace(reading, trace=tr.Trace(), counters={})
+    assert bare.spans == []
+    assert all(layers.span_idle_ms(bare, g) is None for g in spans.GROUPS)
+    assert layers.bag_slot_fill(bare) is None
+
+
+def test_traced_run_reads_program_spans_and_counters(tmp_path):
+    """A ``--trace 1`` run hands its readers the program's spans in the
+    window and the change of every integer counter of the engine: the
+    result line carries the slot fill the counters give and the split of
+    its kept trace (CPU: no device plane, so all of a span counts
+    idle)."""
+    names = [f"{g}.backlog" for g in spans.GROUPS] + [
+        "bag_slot_fill.backlog"]
+    cell = _tiny_cell(names)
+    kept = tmp_path / "run.xplane.pb"
+    out = harness.run(cell, SEED + 1, 0.3, True, time.perf_counter(),
+                      on_chip=False, keep_trace=kept)
+    assert out["correct"], out["checks"]
+    got = spans.split(spans.load(kept))
+    for group in spans.GROUPS:
+        assert out["metrics"][f"{group}.backlog"]["value"] == pytest.approx(
+            got[group])
+    fill = out["metrics"]["bag_slot_fill.backlog"]["value"]
+    assert 0 < fill < 100

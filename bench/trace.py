@@ -1,12 +1,13 @@
 """Reduction of a profiler trace to device busy time, idle gaps, time per
-XLA module, and the harness's own host spans.
+XLA module, and the host spans of the harness and of the program.
 
 The JAX profiler writes an ``.xplane.pb``; ``jax.profiler.ProfileData``
 reads it.  Each TPU is a plane ``/device:TPU:<n>`` whose ``XLA Ops`` line
 holds one event per operation run and whose ``XLA Modules`` line holds
 one event per executable run; the harness's ``TraceAnnotation`` spans are
-events named ``bench.<what>`` on the host plane.  Device and host events
-share the host's clock, so spans and device intervals can be intersected.
+events named ``bench.<what>`` on the host plane, and the served path's own
+are named ``repro.<step>``.  Device and host events share the host's
+clock, so spans and device intervals can be intersected.
 Times here are in nanoseconds unless a name says seconds.
 """
 from __future__ import annotations
@@ -23,6 +24,7 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 _RUN_ID = re.compile(r"\(\d+\)$")
 
 
@@ -30,7 +32,8 @@ _RUN_ID = re.compile(r"\(\d+\)$")
 class Trace:
     """What the reduction needs of one trace: per device, the merged
     intervals in which an operation ran; every module run as (name,
-    start, end); every harness span as (name, start, end)."""
+    start, end); every harness and program span as (name, start, end), by
+    start."""
     busy: List[List[Interval]] = field(default_factory=list)
     modules: List[Tuple[str, float, float]] = field(default_factory=list)
     spans: List[Tuple[str, float, float]] = field(default_factory=list)
@@ -89,13 +92,20 @@ def from_profile(profile) -> Trace:
                     tr.modules += [(module_name(ev.name), ev.start_ns,
                                     ev.end_ns) for ev in line.events]
             tr.busy.append(merge(ops))
-        elif plane.name == HOST_PLANE:
-            for line in plane.lines:
-                tr.spans += [(ev.name, ev.start_ns, ev.end_ns)
-                             for ev in line.events
-                             if ev.name.startswith(SPAN_PREFIX)]
-    tr.spans.sort(key=lambda x: x[1])
+    tr.spans = sorted(((ev.name, ev.start_ns, ev.end_ns) for ev in
+                       host_events(profile, (SPAN_PREFIX, PROGRAM_PREFIX))),
+                      key=lambda x: x[1])
     return tr
+
+
+def host_events(profile, prefixes: Tuple[str, ...]):
+    """The host plane's events whose names start with one of
+    ``prefixes``."""
+    for plane in profile.planes:
+        if plane.name == HOST_PLANE:
+            for line in plane.lines:
+                yield from (ev for ev in line.events
+                            if ev.name.startswith(prefixes))
 
 
 def load(path: Path) -> Trace:

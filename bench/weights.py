@@ -1,14 +1,13 @@
-"""Weights of a DLRM configuration, made on the device from the seed.
+"""Weights of a configuration, made on the device from the seed.
 
 One jitted call turns the seed into every parameter, fp32, in the layout
-the served model takes (``embed`` (T, R, D), ``proj`` (T, K), ``bottom``
-and ``top`` MLPs of ``w<i>``/``b<i>``).  Scales keep every stage near
-unit variance, so that the embedding path carries a large share of each
-score and a fault in it shows: tables N(0, 1/P) (a pooled vector of about
-0.7 P valid rows), projection N(0, 1/T), MLP weights N(0, 1/fan_in), and
-biases N(0, 0.01^2) so that the bias path is checked too.  The reference
-calls this again after the program's state is freed, and gets the same
-arrays: the function is deterministic in the seed.
+the served model takes, as the configuration's family lists them
+(``families.<family>.leaves``: path, shape and scale of each, in order).
+Scales keep every stage near unit variance, so that the embedding path
+carries a large share of each score and a fault in it shows: MLP weights
+N(0, 1/fan_in), and biases N(0, 0.01^2) so that the bias path is checked
+too.  The reference calls this again after the program's state is freed,
+and gets the same arrays: the function is deterministic in the seed.
 """
 from __future__ import annotations
 
@@ -19,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.families import family
+
 
 def key_data(seed: int) -> np.ndarray:
     """The seed's 64 low bits as a threefry key (any whole seed)."""
@@ -26,24 +27,14 @@ def key_data(seed: int) -> np.ndarray:
     return np.asarray([s >> 32, s & 0xFFFFFFFF], np.uint32)
 
 
-def _mlp(dims: List[int]) -> List[Tuple[str, Tuple[int, ...], float]]:
+def mlp_leaves(dims: List[int]
+               ) -> List[Tuple[str, Tuple[int, ...], float]]:
+    """``w<i>`` (fan_in, fan_out) and ``b<i>`` of an MLP of widths
+    ``dims``, with their scales."""
     out = []
     for i, (a, b) in enumerate(zip(dims[:-1], dims[1:])):
         out += [(f"w{i}", (a, b), a ** -0.5), (f"b{i}", (b,), 0.01)]
     return out
-
-
-def _leaves(cfg: Dict):
-    T, R, D = cfg["num_tables"], cfg["rows_per_table"], cfg["embed_dim"]
-    K = cfg["interaction_proj"]
-    f = K + 1
-    bottom = [cfg["num_dense_features"]] + list(cfg["bottom_mlp"])
-    top = [cfg["bottom_mlp"][-1] + f * (f - 1) // 2] + list(cfg["top_mlp"])
-    P = cfg["avg_pooling"]
-    return ([(("embed",), (T, R, D), P ** -0.5),
-             (("proj",), (T, K), T ** -0.5)]
-            + [(("bottom", n), s, sc) for n, s, sc in _mlp(bottom)]
-            + [(("top", n), s, sc) for n, s, sc in _mlp(top)])
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -62,4 +53,5 @@ def _make(leaves, kd):
 
 def make(cfg: Dict, seed: int) -> Dict:
     """Every parameter of ``cfg`` from ``seed``, on the default device."""
-    return _make(tuple(_leaves(cfg)), jnp.asarray(key_data(seed)))
+    return _make(tuple(family(cfg).leaves(cfg)),
+                 jnp.asarray(key_data(seed)))
