@@ -113,8 +113,8 @@ def _check_pooling(engine, params, idx):
         if kind not in kernel:
             bag = (ops.embedding_bag_nmp_flat if engine.mn_nmp[j]
                    else ops.embedding_bag_fused_flat)
-            offsets = jnp.asarray([engine._shard_slot[j][t] * engine.R
-                                   for t in tids], jnp.int32)
+            offsets = jnp.asarray([engine._shard_off[j][t] for t in tids],
+                                  jnp.int32)
             hlo = bag.lower(engine._shard_flat[j], offsets,
                             sub).compile().as_text()
             kernel[kind] = "tpu_custom_call" in hlo

@@ -1,5 +1,6 @@
 """Config registry: ``--arch <id>`` resolution for every assigned
-architecture plus the paper's own RM1/RM2 models."""
+architecture plus the recommendation models: the paper's own RM1/RM2 and
+MLPerf's DLRM-DCNv2."""
 from __future__ import annotations
 
 import importlib
@@ -25,9 +26,12 @@ _ARCH_MODULES: Dict[str, str] = {
     "rwkv6-3b": "rwkv6_3b",
     "rm1": "rm1",
     "rm2": "rm2",
+    "dcnv2": "dcnv2",
 }
 
-ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES if a not in ("rm1", "rm2")]
+RECOMMENDATION_ARCHS = ("rm1", "rm2", "dcnv2")
+ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES
+                             if a not in RECOMMENDATION_ARCHS]
 
 
 def _module(arch: str):

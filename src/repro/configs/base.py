@@ -57,7 +57,14 @@ class VLMConfig:
 
 @dataclass(frozen=True)
 class DLRMConfig:
-    """Paper's own recommendation models (RM1/RM2, Fig. 1)."""
+    """Recommendation models: the paper's own (RM1/RM2, Fig. 1) and
+    MLPerf's DLRM-DCNv2.
+
+    Tables and bags are per table: ``table_rows[t]`` rows and bags of up
+    to ``bag_lengths[t]`` lookups (the index block of a row is
+    ``(num_tables, max(bag_lengths))``, -1 past each table's length).
+    Left ``None`` they are the uniform case, ``rows_per_table`` and
+    ``avg_pooling`` for every table."""
     num_tables: int = 64
     rows_per_table: int = 1_000_000      # mean; tables drawn heterogeneous
     embed_dim: int = 128
@@ -69,7 +76,47 @@ class DLRMConfig:
     # before the pairwise-dot interaction (DLRM-v2/DCN-style compression;
     # keeps DenseNet realistic at hundreds of tables)
     interaction_proj: int = 64
+    table_rows: Optional[Tuple[int, ...]] = None
+    bag_lengths: Optional[Tuple[int, ...]] = None
+    # "dot": projected pairwise dot (above); "dcn": DCN-V2's low-rank
+    # cross network over [bottom, pooled_1..T] (Wang et al., arXiv
+    # 2008.13535; torchrec LowRankCrossNet), ReLU after every bottom layer
+    interaction: str = "dot"
+    dcn_num_layers: int = 3
+    dcn_low_rank_dim: int = 512
     # generation scaling handled by rm1/rm2 config modules
+
+    def __post_init__(self):
+        for name in ("table_rows", "bag_lengths"):
+            v = getattr(self, name)
+            if v is not None:
+                object.__setattr__(self, name, tuple(int(x) for x in v))
+                if len(v) != self.num_tables:
+                    raise ValueError(f"{name} has {len(v)} entries for "
+                                     f"{self.num_tables} tables")
+        if self.interaction not in ("dot", "dcn"):
+            raise ValueError(f"unknown interaction {self.interaction!r}")
+
+    @property
+    def rows(self) -> Tuple[int, ...]:
+        """Rows of each table."""
+        return self.table_rows or (self.rows_per_table,) * self.num_tables
+
+    @property
+    def bags(self) -> Tuple[int, ...]:
+        """Longest bag of each table: its slots in a row's index block."""
+        return self.bag_lengths or (self.avg_pooling,) * self.num_tables
+
+    @property
+    def bag_width(self) -> int:
+        """Slots a table takes in a row's index block: the longest bag."""
+        return max(self.bags)
+
+    @property
+    def cross_width(self) -> int:
+        """Width of the DCN cross network's input: bottom output and the
+        T pooled vectors."""
+        return self.bottom_mlp[-1] + self.num_tables * self.embed_dim
 
 
 @dataclass(frozen=True)

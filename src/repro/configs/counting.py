@@ -124,44 +124,59 @@ def active_param_count(cfg: ModelConfig) -> int:
     return n
 
 
+def _mlp_flops(dims) -> int:
+    return sum(2 * a * b for a, b in zip(dims[:-1], dims[1:]))
+
+
+def _mlp_param_count(dims) -> int:
+    return sum(a * b + b for a, b in zip(dims[:-1], dims[1:]))
+
+
+def _dlrm_top_dims(r):
+    if r.interaction == "dcn":
+        return (r.cross_width,) + r.top_mlp
+    f = r.interaction_proj + 1
+    return (r.bottom_mlp[-1] + f * (f - 1) // 2,) + r.top_mlp
+
+
 def dlrm_dense_flops(cfg: ModelConfig) -> int:
-    """DenseNet FLOPs per sample (bottom MLP + proj + interaction + top)."""
+    """DenseNet FLOPs per sample: bottom MLP, the interaction (the
+    projection and pairwise dots, or the DCN cross layers' two low-rank
+    matmuls each), top MLP."""
     r = cfg.dlrm
-    f = 0
-    dims = (r.num_dense_features,) + r.bottom_mlp
-    for a, b in zip(dims[:-1], dims[1:]):
-        f += 2 * a * b
-    f += 2 * r.num_tables * r.interaction_proj * r.embed_dim
-    nf = r.interaction_proj + 1
-    f += 2 * nf * nf * r.embed_dim
-    inter = nf * (nf - 1) // 2
-    dims = (r.bottom_mlp[-1] + inter,) + r.top_mlp
-    for a, b in zip(dims[:-1], dims[1:]):
-        f += 2 * a * b
-    return f
+    f = _mlp_flops((r.num_dense_features,) + r.bottom_mlp)
+    if r.interaction == "dcn":
+        f += r.dcn_num_layers * 4 * r.cross_width * r.dcn_low_rank_dim
+    else:
+        f += 2 * r.num_tables * r.interaction_proj * r.embed_dim
+        nf = r.interaction_proj + 1
+        f += 2 * nf * nf * r.embed_dim
+    return f + _mlp_flops(_dlrm_top_dims(r))
+
+
+def dlrm_lookups(cfg: ModelConfig) -> int:
+    """Embedding lookups per sample at full bags: sum over tables of the
+    bag length."""
+    return sum(cfg.dlrm.bags)
 
 
 def dlrm_sparse_bytes(cfg: ModelConfig) -> float:
     """SparseNet bytes touched per sample (sum over tables of pooling x row)."""
-    r = cfg.dlrm
-    return r.num_tables * r.avg_pooling * r.embed_dim * 4
+    return dlrm_lookups(cfg) * cfg.dlrm.embed_dim * 4
 
 
 def dlrm_size_bytes(cfg: ModelConfig) -> int:
     r = cfg.dlrm
-    return r.num_tables * r.rows_per_table * r.embed_dim * 4
+    return sum(r.rows) * r.embed_dim * 4
 
 
 def _dlrm_params(cfg: ModelConfig) -> int:
     r = cfg.dlrm
-    n = r.num_tables * r.rows_per_table * r.embed_dim
-    n += r.num_tables * r.interaction_proj        # interaction projection
-    dims = (r.num_dense_features,) + r.bottom_mlp
-    for a, b in zip(dims[:-1], dims[1:]):
-        n += a * b + b
-    f = r.interaction_proj + 1
-    inter = f * (f - 1) // 2
-    dims = (r.bottom_mlp[-1] + inter,) + r.top_mlp
-    for a, b in zip(dims[:-1], dims[1:]):
-        n += a * b + b
-    return n
+    n = sum(r.rows) * r.embed_dim
+    if r.interaction == "dcn":
+        W, k = r.cross_width, r.dcn_low_rank_dim
+        n += r.dcn_num_layers * (2 * W * k + W)    # V, W and b per layer
+    else:
+        n += r.num_tables * r.interaction_proj    # interaction projection
+    n += _mlp_param_count((r.num_dense_features,) + r.bottom_mlp)
+    return n + _mlp_param_count(_dlrm_top_dims(r))

@@ -100,16 +100,18 @@ class StageTimes:
 class ServingUnitModel:
     def __init__(self, model: ModelConfig, unit: UnitSpec,
                  routing_imbalance: float = 1.0):
-        assert model.family == "dlrm"
+        if model.dlrm is None:
+            raise ValueError(f"{model.name!r} has no embedding tables "
+                             f"(cfg.dlrm) for a serving unit to model")
         self.model = model
         self.unit = unit
         self.imbalance = max(1.0, routing_imbalance)
         r = model.dlrm
         self.sparse_bytes = counting.dlrm_sparse_bytes(model)
         self.dense_flops = counting.dlrm_dense_flops(model)
-        self.idx_bytes = r.num_tables * r.avg_pooling * 4
+        self.idx_bytes = counting.dlrm_lookups(model) * 4
         self.fsum_bytes = r.num_tables * r.embed_dim * 4
-        self.hash_ops = r.num_tables * r.avg_pooling
+        self.hash_ops = counting.dlrm_lookups(model)
         self.size_bytes = counting.dlrm_size_bytes(model)
 
     # ------------------------------------------------------------ checks

@@ -226,26 +226,40 @@ def zipf_indices(rng: np.random.RandomState, shape, num_rows: int,
 
 
 def dlrm_batch(cfg, batch: int, rng: np.random.RandomState,
-               pooling_sigma: float = 0.3, alpha: float = 0.0):
+               pooling_sigma: Optional[float] = 0.3, alpha: float = 0.0):
     """Synthetic click-log batch for a DLRM config: dense features,
     per-table pooled index lists (-1 padded), labels.
 
-    ``alpha > 0`` switches index generation from uniform hashing to a
-    truncated Zipf over each table's rows (the skewed production access
-    pattern); ``alpha = 0`` keeps the exact uniform-hash RNG stream of
-    earlier revisions, so seeded goldens are unaffected."""
+    Table t draws ids in ``[0, rows[t])`` into its first ``bags[t]``
+    slots of a ``(batch, num_tables, bag_width)`` block; a lognormal
+    tail per bag (median 0.7 of the table's bag length, shape
+    ``pooling_sigma``) is masked out, or none where ``pooling_sigma`` is
+    None (every bag full, as MLPerf's multi-hot lookups are).  ``alpha >
+    0`` switches index generation from uniform hashing to a truncated
+    Zipf over each table's rows (the skewed production access pattern);
+    ``alpha = 0`` keeps the exact uniform-hash RNG stream of earlier
+    revisions, so seeded goldens are unaffected."""
     r = cfg.dlrm
     dense = rng.randn(batch, r.num_dense_features).astype(np.float32)
-    P = r.avg_pooling
+    T, P = r.num_tables, r.bag_width
+    rows = np.asarray(r.rows)
     if alpha > 0.0:
-        idx = zipf_indices(rng, (batch, r.num_tables, P),
-                           r.rows_per_table, alpha)
+        u = rng.uniform(size=(batch, T, P))
+        idx = np.empty((batch, T, P), np.int32)
+        for t in range(T):
+            idx[:, t] = np.searchsorted(zipf_row_cdf(rows[t], alpha),
+                                        u[:, t], side="right")
     else:
-        raw = rng.randint(0, 1 << 31, size=(batch, r.num_tables, P))
-        idx = hash_features(raw, r.rows_per_table)
-    # variable pooling: mask out a lognormal-distributed tail per bag
-    lens = np.clip(rng.lognormal(np.log(max(P * 0.7, 1.0)), pooling_sigma,
-                                 size=(batch, r.num_tables)), 1, P)
+        raw = rng.randint(0, 1 << 31, size=(batch, T, P))
+        idx = hash_features(raw, rows[None, :, None])
+    # each table's bags end at its length; variable pooling masks out a
+    # lognormal-distributed tail per bag below that
+    bags = np.asarray(r.bags)
+    lens = np.broadcast_to(bags, (batch, T))
+    if pooling_sigma is not None:
+        lens = np.clip(rng.lognormal(np.log(np.maximum(bags * 0.7, 1.0)),
+                                     pooling_sigma, size=(batch, T)),
+                       1, bags)
     mask = np.arange(P)[None, None, :] < lens[..., None]
     idx = np.where(mask, idx, -1).astype(np.int32)
     labels = rng.binomial(1, 0.2, size=batch).astype(np.int32)

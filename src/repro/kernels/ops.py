@@ -40,10 +40,11 @@ def embedding_bag_fused(tables, idx, interpret: bool = None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def embedding_bag_fused_flat(flat_table, offsets, idx, interpret: bool = None):
+def embedding_bag_fused_flat(flat_table, offsets, idx, lens=None,
+                             interpret: bool = None):
     if interpret is None:
         interpret = _default_interpret()
-    return _eb.embedding_bag_fused_flat(flat_table, offsets, idx,
+    return _eb.embedding_bag_fused_flat(flat_table, offsets, idx, lens,
                                         interpret=interpret)
 
 
@@ -55,10 +56,11 @@ def embedding_bag_nmp(tables, idx, interpret: bool = None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def embedding_bag_nmp_flat(flat_table, offsets, idx, interpret: bool = None):
+def embedding_bag_nmp_flat(flat_table, offsets, idx, lens=None,
+                           interpret: bool = None):
     if interpret is None:
         interpret = _default_interpret()
-    return _eb.embedding_bag_nmp_flat(flat_table, offsets, idx,
+    return _eb.embedding_bag_nmp_flat(flat_table, offsets, idx, lens,
                                       interpret=interpret)
 
 

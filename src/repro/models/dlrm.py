@@ -16,10 +16,11 @@ paper's near-memory reduction, realized on TPU as a VMEM-local reduction
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed import sharding as shd
@@ -35,26 +36,38 @@ def _mlp_tables(dims, prefix_names=("embed", None)):
     return t
 
 
-def _mlp_apply(t, x, n):
+def _mlp_apply(t, x, n, relu_last: bool = False):
     for i in range(n):
         x = x @ t[f"w{i}"] + t[f"b{i}"]
-        if i < n - 1:
+        if i < n - 1 or relu_last:
             x = jax.nn.relu(x)
     return x
 
 
+def table_key(t: int) -> str:
+    """Key of table ``t`` in a per-table ``embed`` dict."""
+    return f"t{t}"
+
+
+def table_list(embed) -> List[jax.Array]:
+    """The tables of an ``embed`` parameter as (R_t, D) arrays, in table
+    order: a (T, R, D) stack, a sequence of tables, or a per-table dict
+    keyed by ``table_key``."""
+    if isinstance(embed, dict):
+        return [embed[table_key(t)] for t in range(len(embed))]
+    return list(embed)
+
+
 def embedding_bag_ref(tables, idx):
-    """tables: (T, R, D); idx: (B, T, P) -> pooled (B, T, D).
+    """tables: T (R_t, D) tables as ``table_list`` takes them; idx:
+    (B, T, P) -> pooled (B, T, D).
 
     Shard-local gather+sum; -1 indices are padding (masked out).
     """
     valid = (idx >= 0)[..., None]
     safe = jnp.maximum(idx, 0)
-
-    def per_table(table, ix):              # (R, D), (B, P)
-        return jnp.take(table, ix, axis=0)  # (B, P, D)
-
-    rows = jax.vmap(per_table, in_axes=(0, 1), out_axes=1)(tables, safe)
+    rows = jnp.stack([jnp.take(tb, safe[:, t], axis=0)      # (B, P, D)
+                      for t, tb in enumerate(table_list(tables))], axis=1)
     return jnp.where(valid, rows, 0.0).sum(axis=2)
 
 
@@ -68,10 +81,26 @@ class DLRMModel:
     def _tables(self):
         r = self.cfg.dlrm
         bot = (r.num_dense_features,) + r.bottom_mlp
+        if r.table_rows is None:     # RM1's uniform tables: one stack
+            embed = Spec((r.num_tables, r.rows_per_table, r.embed_dim),
+                         ("table_shard", "table_rows", None), "normal:0.01")
+        else:
+            embed = {table_key(t): Spec((rows, r.embed_dim),
+                                        ("table_rows", None), "normal:0.01")
+                     for t, rows in enumerate(r.rows)}
+        if r.interaction == "dcn":
+            W, k = r.cross_width, r.dcn_low_rank_dim
+            cross = {}
+            for i in range(r.dcn_num_layers):
+                cross[f"v{i}"] = Spec((W, k), (None, None))
+                cross[f"w{i}"] = Spec((k, W), (None, None))
+                cross[f"b{i}"] = Spec((W,), (None,), "zeros")
+            return {"embed": embed, "bottom": _mlp_tables(bot),
+                    "cross": cross,
+                    "top": _mlp_tables((W,) + r.top_mlp)}
         top = (r.bottom_mlp[-1] + self.inter,) + r.top_mlp
         return {
-            "embed": Spec((r.num_tables, r.rows_per_table, r.embed_dim),
-                          ("table_shard", "table_rows", None), "normal:0.01"),
+            "embed": embed,
             "proj": Spec((r.num_tables, r.interaction_proj), (None, None),
                          "normal:0.05"),
             "bottom": _mlp_tables(bot),
@@ -97,19 +126,37 @@ class DLRMModel:
         """SparseNet G_S: gather+pool all tables -> (B, T, D).
 
         `use_kernel=True` runs the fused multi-table Pallas embedding-bag
-        (one call for the whole table stack); otherwise the jnp reference.
+        (one call over the tables laid back to back, each walked to its
+        bag length); otherwise the jnp reference.
         This is the shard-local half of the query path — the ClusterEngine
         calls it per MN shard with that shard's table subset.
         """
         if use_kernel:
             from repro.kernels import ops
-            return ops.embedding_bag_fused(params["embed"], idx)
+            tables = table_list(params["embed"])
+            offsets = np.cumsum([0] + [t.shape[0] for t in tables[:-1]])
+            return ops.embedding_bag_fused_flat(
+                jnp.concatenate(tables), jnp.asarray(offsets, jnp.int32),
+                idx, jnp.asarray(self.cfg.dlrm.bags, jnp.int32))
         return embedding_bag_ref(params["embed"], idx)
 
     def dense_forward(self, params, dense, pooled):
         """DenseNet G_D on already-pooled embeddings (the CN-side half:
         what runs after the Fsum gather returns from the MN pool)."""
         r = self.cfg.dlrm
+        if r.interaction == "dcn":
+            # x0 = [bottom, pooled_1..T]; x_{l+1} = x0 * (W_l V_l x_l + b_l)
+            # + x_l; then the top MLP
+            bot = _mlp_apply(params["bottom"], dense, len(r.bottom_mlp),
+                             relu_last=True)
+            pooled = shd.lsc(pooled, "batch", None, None)       # Fsum gather
+            x0 = jnp.concatenate(
+                [bot, pooled.astype(bot.dtype).reshape(bot.shape[0], -1)],
+                axis=-1)
+            x, c = x0, params["cross"]
+            for i in range(r.dcn_num_layers):
+                x = x0 * ((x @ c[f"v{i}"]) @ c[f"w{i}"] + c[f"b{i}"]) + x
+            return _mlp_apply(params["top"], x, len(r.top_mlp))[..., 0]
         bot = _mlp_apply(params["bottom"], dense, len(r.bottom_mlp))
         pooled = shd.lsc(pooled, "batch", None, None)           # Fsum gather
         pooled = jnp.einsum("btd,tk->bkd", pooled.astype(bot.dtype),
@@ -149,7 +196,7 @@ class DLRMModel:
             "dense": jax.ShapeDtypeStruct((B, r.num_dense_features),
                                           jnp.float32),
             "indices": jax.ShapeDtypeStruct(
-                (B, r.num_tables, r.avg_pooling), jnp.int32),
+                (B, r.num_tables, r.bag_width), jnp.int32),
         }
         if kind == "train":
             spec["labels"] = jax.ShapeDtypeStruct((B,), jnp.int32)

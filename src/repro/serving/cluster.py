@@ -85,10 +85,18 @@ Wall-clock tracing: the served path marks its host steps with
 here: ``route``, then per MN ``scatter``/``gather``/``account``, then
 ``dense``; the rest in ``serving.timeline``).  They land in a profiler
 trace on the device's clock and cost about a microsecond each when no
-profiler runs.  ``bag_slots``/``bag_slots_valid`` count the index slots
-of the bag calls and the valid ones among them; ``bag_lanes`` counts the
-slots the kernels walk once the lane axis (tables of a fused call, bags
-of an NMP call) is padded to a multiple of 8.
+profiler runs.  ``bag_slots``/``bag_slots_valid`` count the slots of the
+bag calls' index blocks and the valid ones among them;
+``bag_slots_walked`` counts the slots the kernels walk, each table's bag
+length in every bag (equal to ``bag_slots`` where every table's bags are
+as long as the block is wide); ``bag_lanes`` counts the index-block
+slots once the lane axis (tables of a fused call, bags of an NMP call)
+is padded to a multiple of 8.
+
+Tables may differ in rows and bag length (``DLRMConfig.rows`` and
+``bags``): each MN's shard holds its tables' rows back to back, found by
+per-table row offsets, and the kernels walk each table's bags to that
+table's length.
 """
 from __future__ import annotations
 
@@ -108,6 +116,7 @@ from repro.core import hardware as hw
 from repro.core.hardware import NODE_TYPES
 from repro.core.serving_unit import ServingUnitModel, UnitSpec
 from repro.kernels.embedding_bag import pad_to_lanes
+from repro.models.dlrm import embedding_bag_ref, table_key, table_list
 from repro.serving.cache import CacheStats, RowCache
 from repro.serving.engine import Request, Result
 
@@ -303,14 +312,16 @@ class ClusterEngine:
     ``t`` is global tid ``_tbl_off[k] + t`` — so placement, routing,
     shards, hedging, and the caches all run unchanged over the union;
     only hot/cold classification and cache budgets are attributed per
-    model.  The shared pool needs a uniform table shape ``(rows, dim)``
-    across members (table *counts* and pooling factors may differ).  A
-    fleet of one is exactly the historical single-model engine."""
+    model.  The shared pool needs one embedding width across members
+    (table counts, rows and bag lengths may differ).  A fleet of one is
+    exactly the historical single-model engine.
+
+    The engine uses of a model its ``cfg.dlrm`` (tables, rows, bag
+    lengths, width) and its ``dense_forward``."""
 
     def __init__(self, model, params, cfg: Optional[ClusterConfig] = None,
                  unit_model: Optional[ServingUnitModel] = None,
                  fleet: Optional[Sequence[Tuple[str, object, object]]] = None):
-        assert model.cfg.family == "dlrm"
         self.model = model
         self.cfg = cfg or ClusterConfig()
         self.fleet = (list(fleet) if fleet is not None
@@ -321,28 +332,32 @@ class ClusterEngine:
                              "(model, params) pair")
         self.model_names = [n for n, _, _ in self.fleet]
         self.n_models = len(self.fleet)
-        r = model.cfg.dlrm
-        self.R, self.D = r.rows_per_table, r.embed_dim
         self._tbl_off: List[int] = []
         self._tbl_count: List[int] = []
         self._tbl_owner: List[int] = []
+        self._bag_len: List[int] = []       # per global table id
         self.tables = []
         for k, (name, m, _) in enumerate(self.fleet):
-            assert m.cfg.family == "dlrm"
-            rk = m.cfg.dlrm
-            if (rk.rows_per_table, rk.embed_dim) != (self.R, self.D):
+            rk = getattr(m.cfg, "dlrm", None)
+            if rk is None or not hasattr(m, "dense_forward"):
                 raise ValueError(
-                    f"fleet model {name!r} has table shape "
-                    f"({rk.rows_per_table}, {rk.embed_dim}); the shared "
-                    f"MN pool needs the uniform shape "
-                    f"({self.R}, {self.D})")
+                    f"model {name!r} has no embedding tables (cfg.dlrm) "
+                    f"and dense step (dense_forward) to serve")
+            if k == 0:
+                self.D = rk.embed_dim
+            elif rk.embed_dim != self.D:
+                raise ValueError(
+                    f"fleet model {name!r} has embedding width "
+                    f"{rk.embed_dim}; the shared MN pool needs one width, "
+                    f"{self.D}")
             off = len(self.tables)
             self._tbl_off.append(off)
             self._tbl_count.append(rk.num_tables)
             self._tbl_owner += [k] * rk.num_tables
-            self.tables += [em.TableInfo(off + t, self.R, self.D,
-                                         float(rk.avg_pooling))
-                            for t in range(rk.num_tables)]
+            self._bag_len += list(rk.bags)
+            self.tables += [em.TableInfo(off + t, rows, self.D, float(bag))
+                            for t, (rows, bag) in enumerate(zip(rk.rows,
+                                                                rk.bags))]
         self.T = len(self.tables)
         self._fleet_params = [p for _, _, p in self.fleet]
         self.params = (params if self.n_models == 1
@@ -417,24 +432,26 @@ class ClusterEngine:
         self.retired_gather_bytes = 0.0
         self._mn_stage_max_sum = 0.0                # per-batch gating stage
         self._n_batches = 0
-        # index slots of the bag calls (B x tables x P per MN call,
-        # padding included), the valid ones among them, and the slots
-        # walked with the lane axis padded to 8 (bag_slots / bag_lanes
-        # is the lane fill)
+        # index-block slots of the bag calls (B x tables x block width
+        # per MN call, padding included), the valid ones among them, the
+        # slots the kernels walk (B x the tables' bag lengths), and the
+        # index-block slots with the lane axis padded to 8 (bag_slots /
+        # bag_lanes is the lane fill)
         self.bag_slots = 0
         self.bag_slots_valid = 0
+        self.bag_slots_walked = 0
         self.bag_lanes = 0
         # pipelined-execution introspection: the most recent serve()
         # call's per-batch trace and resource clocks (serving.pipeline)
         self.last_trace: List = []
         self.last_resources: List = []
 
-    def _fleet_embed(self) -> Dict[str, jnp.ndarray]:
-        """Concatenate the fleet members' embedding banks along the table
-        axis, in fleet order — global tid `_tbl_off[k] + t` indexes model
-        k's local table t directly."""
-        return {"embed": jnp.concatenate(
-            [p["embed"] for p in self._fleet_params], axis=0)}
+    def _fleet_embed(self) -> Dict:
+        """The fleet members' tables in fleet order, one per-table dict —
+        global tid `_tbl_off[k] + t` is model k's local table t."""
+        tables = [t for p in self._fleet_params
+                  for t in table_list(p["embed"])]
+        return {"embed": {table_key(g): t for g, t in enumerate(tables)}}
 
     def _allocate(self, tables, capacities, mn_types, n_replicas,
                   access_bytes=None):
@@ -458,28 +475,28 @@ class ClusterEngine:
         the departing shards."""
         total = sum(t.size_bytes for t in self.tables)
         cap = (math.ceil(self.cfg.n_replicas * total / m_mn)
-               + self.tables[0].size_bytes)
+               + max(t.size_bytes for t in self.tables))
         return [cap] * m_mn
 
     # ------------------------------------------------------------- shards
     def _build_shards(self) -> None:
         """Materialize each MN's replica shard: the tables the allocator
-        placed on it, flattened row-wise for the fused kernel."""
-        embed = self.params["embed"]                      # (T, R, D)
+        placed on it, their rows back to back for the bag kernels, and
+        each table's row offset in it."""
+        tables = table_list(self.params["embed"])
         self._shard_tids: List[List[int]] = []
-        self._shard_slot: List[Dict[int, int]] = []
+        self._shard_off: List[Dict[int, int]] = []
         self._shard_flat: List[jax.Array] = []
+        self._bag_args: Dict[Tuple[int, Tuple[int, ...]], Tuple] = {}
         for j in range(self.m_mn):
             tids = sorted(t for t, reps in self.alloc.replicas.items()
                           if j in reps)
             self._shard_tids.append(tids)
-            self._shard_slot.append({t: s for s, t in enumerate(tids)})
-            if tids:
-                flat = jnp.reshape(embed[jnp.asarray(tids)],
-                                   (len(tids) * self.R, self.D))
-            else:
-                flat = jnp.zeros((0, self.D), embed.dtype)
-            self._shard_flat.append(flat)
+            offs = np.cumsum([0] + [self.tables[t].rows for t in tids])
+            self._shard_off.append(dict(zip(tids, offs.tolist())))
+            self._shard_flat.append(
+                jnp.concatenate([tables[t] for t in tids]) if tids
+                else jnp.zeros((0, self.D), tables[0].dtype))
 
     # ------------------------------------------------------------- caching
     def _make_caches(self, n_cn: int) -> List[RowCache]:
@@ -793,17 +810,23 @@ class ClusterEngine:
         accumulate slots in ascending order, so the scores are bitwise
         independent of the pool's node-type mix.
         """
-        slots = np.asarray([self._shard_slot[j][t] for t in tids], np.int32)
+        off = self._shard_off[j]
         if self.cfg.use_kernel:
             from repro.kernels import ops
-            offsets = jnp.asarray(slots * self.R)
+            key = (j, tuple(tids))
+            if key not in self._bag_args:    # uploaded once per shard set
+                self._bag_args[key] = (
+                    jnp.asarray([off[t] for t in tids], jnp.int32),
+                    jnp.asarray([self._bag_len[t] for t in tids], jnp.int32))
+            offsets, lens = self._bag_args[key]
             bag = (ops.embedding_bag_nmp_flat if self.mn_nmp[j]
                    else ops.embedding_bag_fused_flat)
-            return bag(self._shard_flat[j], offsets, jnp.asarray(idx_sub))
-        from repro.models.dlrm import embedding_bag_ref
-        stack = self._shard_flat[j].reshape(-1, self.R, self.D)[
-            jnp.asarray(slots)]
-        return embedding_bag_ref(stack, jnp.asarray(idx_sub))
+            return bag(self._shard_flat[j], offsets, jnp.asarray(idx_sub),
+                       lens)
+        flat = self._shard_flat[j]
+        return embedding_bag_ref(
+            [flat[off[t]:off[t] + self.tables[t].rows] for t in tids],
+            jnp.asarray(idx_sub))
 
     def _execute(self, task: int, dense: np.ndarray, idx: np.ndarray,
                  model: int = 0
@@ -854,7 +877,9 @@ class ClusterEngine:
                                  nmp=int(self.mn_nmp[j]),
                                  tables=len(mtids)):
                 cols = [t - off for t in mtids]
-                sub = idx[:, cols, :]
+                # the block is as wide as the longest bag of the MN's tables
+                lens = [self._bag_len[t] for t in mtids]
+                sub = idx[:, cols, :max(lens)]
                 out = self._mn_pool(j, mtids, sub)
             with TraceAnnotation("repro.gather", mn=j):
                 pooled[:, cols, :] = np.asarray(out)
@@ -868,6 +893,7 @@ class ClusterEngine:
                 self.bag_slots += sub.size
                 self.bag_slots_valid += nvalid
                 nb, nt, npool = sub.shape
+                self.bag_slots_walked += nb * sum(lens)
                 if self.mn_nmp[j]:
                     nb = pad_to_lanes(nb)
                 else:

@@ -7,6 +7,7 @@ every query identically regardless of batching, routing, or failures.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs import rm1
@@ -350,3 +351,69 @@ def test_bag_lanes_count_lane_padding(tables, mn_type, batch):
     assert eng.bag_lanes == lanes_b * lanes_t * P
     assert (eng.bag_lanes == eng.bag_slots) == (lanes_b * lanes_t
                                                 == batch * tables)
+
+
+def test_bag_slots_walked_equals_bag_slots_for_uniform_bags(
+        model_and_params):
+    """Every RM1 table's bag is as long as the index block is wide, so the
+    kernels walk every slot of it."""
+    model, params = model_and_params
+    eng = ClusterEngine(model, params, ClusterConfig(
+        n_cn=2, m_mn=4, batch_size=16, n_replicas=2))
+    eng.serve(make_requests(8))
+    assert eng.bag_slots_walked == eng.bag_slots > 0
+
+
+@pytest.mark.parametrize("mn_types", [["ddr_mn"] * 4,
+                                      ["ddr_mn"] * 2 + ["nmp_mn"] * 2])
+def test_uneven_tables_served_at_their_own_rows_and_lengths(mn_types):
+    """DLRM-DCNv2's reduced model (tables of 3 to 1,000 rows, bags of 1 to
+    12, a cross network): each shard holds its tables at their own rows,
+    each MN's index block is as wide as its longest bag, the kernels walk
+    each table's length, and the scores match the model's own forward
+    pass on the reference pooling."""
+    from repro.configs import dcnv2
+    cfg = dcnv2.REDUCED
+    r = cfg.dlrm
+    model = DLRMModel(cfg)
+    params = model.init(0)
+    rng = np.random.RandomState(6)
+    reqs = []
+    for i, size in enumerate((5, 11, 16, 3)):
+        b = dlrm_batch(cfg, size, rng, pooling_sigma=None)
+        reqs.append(Request(i, {"dense": b["dense"],
+                                "indices": b["indices"]}, size, 0.0))
+    eng = ClusterEngine(model, params, ClusterConfig(
+        n_cn=2, m_mn=4, batch_size=8, n_replicas=2, mn_types=mn_types))
+    res, stats = eng.serve(reqs)
+    assert stats.completed == len(reqs)
+    for j, tids in enumerate(eng._shard_tids):
+        assert eng._shard_flat[j].shape[0] == sum(r.rows[t] for t in tids)
+    # full bags in the 35 real rows; batches padded to 8 rows are walked
+    assert eng.bag_slots_valid == 35 * sum(r.bags)
+    assert eng.bag_slots_walked == eng.batches_seen * 8 * sum(r.bags)
+    assert eng.bag_slots < eng.batches_seen * 8 * r.num_tables * r.bag_width
+    want = {q.rid: np.asarray(jax.nn.sigmoid(model.forward(params, {
+        "dense": jnp.asarray(q.payload["dense"]),
+        "indices": jnp.asarray(q.payload["indices"])}))) for q in reqs}
+    for out in res:
+        np.testing.assert_allclose(out.outputs, want[out.rid],
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_fleet_shares_one_width_not_one_row_count(model_and_params):
+    """Fleet members may hold tables of other row counts (a per-table
+    pool); only the embedding width must agree.  The pool's slack per MN
+    is its largest table."""
+    model, params = model_and_params
+    other = CFG.replace(name="rm1-short", dlrm=rm1.DLRMConfig(
+        num_tables=3, rows_per_table=20, embed_dim=8, avg_pooling=5,
+        num_dense_features=8, bottom_mlp=(16, 8), top_mlp=(32, 16, 1)))
+    mb = DLRMModel(other)
+    eng = ClusterEngine(model, params, ClusterConfig(
+        n_cn=1, m_mn=2, batch_size=8), fleet=[("a", model, params),
+                                              ("b", mb, mb.init(1))])
+    assert [t.rows for t in eng.tables] == [64] * 6 + [20] * 3
+    assert isinstance(eng.params["embed"], dict)
+    total = sum(t.size_bytes for t in eng.tables)
+    assert eng.capacities == [total + 64 * 8 * 4] * 2

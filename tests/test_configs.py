@@ -85,3 +85,46 @@ def test_shape_applicability():
     assert ok
     ok, _ = shape_applicable(configs.get_config("zamba2-7b"), long)
     assert ok
+
+
+@pytest.mark.parametrize("name, want", [
+    # (dense FLOPs, sparse bytes, table bytes, parameters) per sample,
+    # as the counts gave them before tables and bags were per table
+    ("rm1-chip", (22708992, 32768000, 1677721600, 423745409)),
+    ("rm2.v0", (962371840, 8192000, 800000000000, 200477443201)),
+    # DLRM-DCNv2 (MLPerf): bottom 340,992 + cross 3 x 4 x 3,456 x 512
+    # + top 10,486,272 FLOPs; 214 lookups of 512 B; 4,196,496 rows of
+    # 128; 16,044,545 dense parameters
+    ("dcnv2-chip", (32060928, 214 * 512, 4196496 * 512,
+                    4196496 * 128 + 16044545)),
+])
+def test_dlrm_counts(name, want):
+    from repro.configs import counting, dcnv2, rm1, rm2
+    cfg = {"rm1-chip": rm1.CHIP_SHARE, "rm2.v0": rm2.CONFIG,
+           "dcnv2-chip": dcnv2.CHIP_SHARE}[name]
+    assert (counting.dlrm_dense_flops(cfg), counting.dlrm_sparse_bytes(cfg),
+            counting.dlrm_size_bytes(cfg), cfg.param_count()) == want
+    assert registry.build(cfg).param_count() == cfg.param_count()
+
+
+def test_dcnv2_reduced_count_matches_init():
+    from repro.configs import dcnv2
+    cfg = dcnv2.REDUCED
+    params = registry.build(cfg).init(0)
+    assert sum(x.size for x in jax.tree.leaves(params)) == cfg.param_count()
+    # each table held at its own rows, no padded (T, R_max, D) stack
+    assert [params["embed"][f"t{t}"].shape[0]
+            for t in range(cfg.dlrm.num_tables)] == list(cfg.dlrm.rows)
+
+
+@pytest.mark.parametrize("name, idx_bytes, hash_ops", [
+    ("rm1-chip", 800 * 80 * 4, 800 * 80),
+    ("dcnv2-chip", 214 * 4, 214),
+])
+def test_serving_unit_counts_lookups_per_table(name, idx_bytes, hash_ops):
+    from repro.configs import counting, dcnv2, rm1
+    from repro.core.serving_unit import ServingUnitModel, UnitSpec
+    cfg = {"rm1-chip": rm1.CHIP_SHARE, "dcnv2-chip": dcnv2.CHIP_SHARE}[name]
+    u = ServingUnitModel(cfg, UnitSpec(2, "cn_1g", 4, "ddr_mn"))
+    assert (u.idx_bytes, u.hash_ops) == (idx_bytes, hash_ops)
+    assert u.dense_flops == counting.dlrm_dense_flops(cfg)

@@ -112,3 +112,21 @@ def test_query_dist_mean_tracks(mean, sigma, seed):
     s = d.sample(np.random.RandomState(seed), 20_000)
     # ceil() biases the mean up by <1; heavy tails add sampling noise
     assert mean * 0.75 <= s.mean() <= mean * 1.3 + 1.0
+
+
+@pytest.mark.parametrize("sigma", [None, 0.3])
+def test_dlrm_batch_per_table_rows_and_bags(sigma):
+    """Table t draws ids below its own rows into at most its own bag
+    length; ``pooling_sigma=None`` fills every bag (MLPerf multi-hot)."""
+    from repro.configs import dcnv2
+    r = dcnv2.REDUCED.dlrm
+    b = q.dlrm_batch(dcnv2.REDUCED, 64, np.random.RandomState(4),
+                     pooling_sigma=sigma)
+    idx = b["indices"]
+    assert idx.shape == (64, r.num_tables, r.bag_width)
+    lens = (idx >= 0).sum(axis=2)
+    for t, (rows, bag) in enumerate(zip(r.rows, r.bags)):
+        assert idx[:, t].max() < rows
+        assert (idx[:, t, bag:] == -1).all()
+        assert (lens[:, t] == bag).all() if sigma is None else (
+            (lens[:, t] >= 1).all() and (lens[:, t] <= bag).all())

@@ -217,6 +217,76 @@ def test_shard_bags_bitwise_at_lane_boundaries(case, T, B, P):
         assert np.count_nonzero(want) > 0
 
 
+@pytest.mark.parametrize("kernel", ["embedding_bag_fused_flat",
+                                    "embedding_bag_nmp_flat"])
+@pytest.mark.parametrize("B", [3, 9])
+def test_shard_bags_walk_each_tables_length(kernel, B):
+    """Tables of uneven rows and bag lengths 1, 7, 8, 9 and 100 in one
+    lane group (and 2, 12, 3 in the next), -1 inside a length: the
+    kernels walk each table's bags only to its length and match the
+    slot-order reference bitwise, at a slot block 100 wide."""
+    rows = [3, 40, 17, 256, 1000, 9, 64, 5, 120, 33, 7]
+    lens = [1, 7, 8, 9, 100, 100, 1, 8, 2, 12, 3]
+    T, D, P = len(rows), 16, max(lens)
+    rng = np.random.RandomState(11)
+    tables = [rng.randn(r, D).astype(np.float32) for r in rows]
+    idx = np.full((B, T, P), -1, np.int32)
+    for t, (r, n) in enumerate(zip(rows, lens)):
+        idx[:, t, :n] = rng.randint(0, r, (B, n))
+    idx[rng.rand(B, T, P) < 0.2] = -1
+    idx[0, 4, 50:60] = -1
+    stack = np.zeros((T, max(rows), D), np.float32)
+    for t, tb in enumerate(tables):
+        stack[t, :len(tb)] = tb
+    flat = jnp.asarray(np.concatenate(tables))
+    offsets = jnp.asarray(np.cumsum([0] + rows[:-1]), jnp.int32)
+    # table order in the call differs from the shard's
+    order = np.array([4, 0, 2, 1, 3, 5, 9, 8, 7, 6, 10])
+    got = np.asarray(getattr(ops, kernel)(
+        flat, offsets[order], jnp.asarray(idx[:, order]),
+        jnp.asarray(np.asarray(lens)[order], jnp.int32)))
+    want = np.asarray(ref.embedding_bag_seq_ref(jnp.asarray(stack[order]),
+                                                jnp.asarray(idx[:, order])))
+    assert np.array_equal(got, want)
+    assert np.count_nonzero(want[:, 0]) > 0
+
+
+@pytest.mark.parametrize("kernel", ["embedding_bag_fused_flat",
+                                    "embedding_bag_nmp_flat"])
+def test_shard_bags_clamp_lengths_to_block(kernel):
+    """A bag length above the index block's width P reads as P: no slot
+    past the block is read, so the result is the full-width walk's."""
+    rng = np.random.RandomState(3)
+    T, R, D, P, B = 3, 20, 16, 5, 9
+    tables = jnp.asarray(rng.randn(T, R, D), jnp.float32)
+    idx = jnp.asarray(_mixed_pooling_idx(rng, R, B, T, P))
+    flat, offsets = tables.reshape(T * R, D), jnp.arange(T) * R
+    got = np.asarray(getattr(ops, kernel)(
+        flat, offsets, idx, jnp.asarray([P, 1000, P + 1], jnp.int32)))
+    assert np.array_equal(got, np.asarray(ref.embedding_bag_seq_ref(
+        tables, idx)))
+
+
+@pytest.mark.parametrize("B", [40, 17])
+def test_nmp_bag_blocks_over_grid_steps(monkeypatch, B):
+    """Where a table's (B, P) index tile would not fit SMEM, the NMP
+    kernel blocks the bags over grid steps (here 3 blocks of 16 and 2 of
+    16, the SMEM budget cut to 16 bags); bitwise the unblocked result."""
+    from repro.kernels import embedding_bag as eb
+    rng = np.random.RandomState(5)
+    T, R, D, P = 3, 50, 16, 9
+    tables = jnp.asarray(rng.randn(T, R, D), jnp.float32)
+    idx = jnp.asarray(_mixed_pooling_idx(rng, R, B, T, P))
+    flat, offsets = tables.reshape(T * R, D), jnp.arange(T) * R
+    whole = np.asarray(eb.embedding_bag_nmp_flat(flat, offsets, idx))
+    monkeypatch.setattr(eb, "NMP_SMEM_WORDS", 16 * 128)
+    assert eb.nmp_bag_block(B, P) == 16
+    blocked = np.asarray(eb.embedding_bag_nmp_flat(flat, offsets, idx))
+    assert np.array_equal(blocked, whole)
+    assert np.array_equal(blocked, np.asarray(ref.embedding_bag_seq_ref(
+        tables, idx)))
+
+
 @pytest.mark.parametrize("B,H,Hkv,S,D,qb,kb", [
     (1, 4, 4, 128, 32, 64, 64),
     (2, 8, 2, 256, 32, 64, 128),

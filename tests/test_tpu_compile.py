@@ -3,7 +3,10 @@
 The served path's programs at the chip-share RM1's widths: both shard
 bag kernels at one MN's call shapes (64 bags x 200, 320, 80 or 201
 tables x 80 slots, D=128, over a 400-table shard) and the CN dense step at RM1's published
-MLP widths.  A compile that passes here is what the v5e compiler accepts;
+MLP widths; and at DLRM-DCNv2's (MLPerf): both kernels at the table
+counts its 4-MN pool routes to one MN (2,048 bags x 1, 7, 8 or 10 tables
+x 100 slots over a 2,098,249-row shard) and its cross-network dense step
+at 2,048 rows.  A compile that passes here is what the v5e compiler accepts;
 it runs nothing, so it says nothing about results or times.
 
 The topology is described inside a module fixture, never at import time:
@@ -17,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import rm1
+from repro.configs import dcnv2, rm1
 from repro.kernels import embedding_bag as eb
 from repro.models.dlrm import DLRMModel
 from repro.serving.cluster import dense_step
@@ -97,4 +100,39 @@ def test_dense_step_compiles_for_v5e(one_chip):
         _spec((B, CFG.num_tables, CFG.embed_dim), jnp.float32,
               one_chip)).compile()
     assert compiled.out_info.shape == (B,)
+    assert compiled.as_text().startswith("HloModule jit_dense_step,")
+
+
+DCN = dcnv2.CHIP_SHARE.dlrm
+DCN_B, DCN_SHARD_ROWS = 2048, 2_098_249
+
+
+@pytest.mark.parametrize("t_mn", [1, 7, 8, 10])
+@pytest.mark.parametrize("kernel", ["embedding_bag_fused_flat",
+                                    "embedding_bag_nmp_flat"])
+def test_bag_kernel_compiles_for_v5e_at_dcn_shapes(kernel, t_mn, one_chip):
+    """DLRM-DCNv2's routed calls at the widest slot block (its 100-lookup
+    table's): the NMP kernel's (2,048, 100) index tile per table does not
+    fit SMEM whole, so its wrapper blocks the bags over grid steps."""
+    fn = functools.partial(getattr(eb, kernel), interpret=False)
+    compiled = jax.jit(fn).lower(
+        _spec((DCN_SHARD_ROWS, DCN.embed_dim), jnp.float32, one_chip),
+        _spec((t_mn,), jnp.int32, one_chip),
+        _spec((DCN_B, t_mn, DCN.bag_width), jnp.int32, one_chip),
+        _spec((t_mn,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (DCN_B, t_mn, DCN.embed_dim)
+
+
+def test_dcn_dense_step_compiles_for_v5e(one_chip):
+    model = DLRMModel(dcnv2.CHIP_SHARE)
+    params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
+                          model.param_shapes())
+    assert set(params) == {"embed", "bottom", "cross", "top"}
+    compiled = dense_step(model).lower(
+        params,
+        _spec((DCN_B, DCN.num_dense_features), jnp.float32, one_chip),
+        _spec((DCN_B, DCN.num_tables, DCN.embed_dim), jnp.float32,
+              one_chip)).compile()
+    assert compiled.out_info.shape == (DCN_B,)
     assert compiled.as_text().startswith("HloModule jit_dense_step,")
