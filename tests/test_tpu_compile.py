@@ -6,8 +6,10 @@ tables x 80 slots, D=128, over a 400-table shard) and the CN dense step at RM1's
 MLP widths; and at DLRM-DCNv2's (MLPerf): both kernels at the table
 counts its 4-MN pool routes to one MN (2,048 bags x 1, 7, 8 or 10 tables
 x 100 slots over a 2,098,249-row shard) and its cross-network dense step
-at 2,048 rows.  A compile that passes here is what the v5e compiler accepts;
-it runs nothing, so it says nothing about results or times.
+at 2,048 rows; and the assembly of the pooled block from the MNs'
+outputs at both models' batch and table counts.  A compile that passes
+here is what the v5e compiler accepts; it runs nothing, so it says
+nothing about results or times.
 
 The topology is described inside a module fixture, never at import time:
 only one process may hold the TPU library, and every test worker imports
@@ -23,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import dcnv2, rm1
 from repro.kernels import embedding_bag as eb
 from repro.models.dlrm import DLRMModel
-from repro.serving.cluster import dense_step
+from repro.serving.cluster import dense_step, gather_pooled
 
 CFG = rm1.CHIP_SHARE.dlrm
 B, T_MN, SHARD_TABLES = 64, 200, 400
@@ -136,3 +138,16 @@ def test_dcn_dense_step_compiles_for_v5e(one_chip):
               one_chip)).compile()
     assert compiled.out_info.shape == (DCN_B,)
     assert compiled.as_text().startswith("HloModule jit_dense_step,")
+
+
+@pytest.mark.parametrize("b, widths", [(B, (200, 200, 200, 200)),
+                                       (DCN_B, (1, 7, 8, 10))])
+def test_gather_pooled_compiles_for_v5e(b, widths, one_chip):
+    """The MNs' pooled outputs concatenated and permuted into the dense
+    step's (B, T, D) block on the device."""
+    D = CFG.embed_dim
+    compiled = gather_pooled.lower(
+        tuple(_spec((b, w, D), jnp.float32, one_chip) for w in widths),
+        _spec((sum(widths),), jnp.int32, one_chip)).compile()
+    assert compiled.out_info.shape == (b, sum(widths), D)
+    assert compiled.as_text().startswith("HloModule jit_gather_pooled,")
